@@ -9,7 +9,8 @@ batch (most elements easy, one very stiff) the per-shard work collapses
 from ``B × max_b(trials)`` to ``Σ_s B_s × max_{b∈s}(trials)``, which is
 why this benchmark speeds up even on a single CPU core running the
 shards serially — it measures eliminated lockstep waste, not core
-count, so it is stable in CI.
+count, so it is stable in CI.  Every rung runs on the CPU backend
+(``JAX_PLATFORMS=cpu``), also on a machine with a chip.
 
 Protocol: the SAME B=64 dopri5/ACA solve (d=256 state, stiffness
 ``logk = 0.5 + 6.6·frac⁵`` — top element ≈40× more trials than the
@@ -99,7 +100,11 @@ def _child(n_dev: int, n_iter: int) -> None:
 
 
 def _run_rung(n_dev: int, n_iter: int) -> dict:
+    # the rungs are forced CPU host devices: a child must never ask for
+    # the chip, which the parent process may already hold (the on-chip
+    # sharded solve is ``chip_smoke.py --four-chips``)
     env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={n_dev}"
     env["PYTHONPATH"] = os.pathsep.join(
         p for p in [os.path.join(_REPO, "src"),

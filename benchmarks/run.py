@@ -19,6 +19,8 @@ from . import (bench_batched_solve, bench_classification,
                bench_serve_node, bench_sharded_solve,
                bench_solver_robustness, bench_threebody,
                bench_timeseries, bench_toy_gradient)
+from repro.compile_cache import enable_compile_cache
+
 from .common import emit
 
 BENCHES = [
@@ -45,6 +47,7 @@ BENCHES = [
 
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--full", action="store_true")
     ap.add_argument("--only", default=None)

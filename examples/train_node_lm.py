@@ -26,6 +26,7 @@ import os
 import jax
 import jax.numpy as jnp
 
+from repro.compile_cache import enable_compile_cache
 from repro.configs import get_config, get_smoke_config
 from repro.configs.node18_cifar import NODE_TRAIN
 from repro.core import NodeConfig
@@ -36,6 +37,7 @@ from repro.train import TrainLoop, TrainLoopConfig, make_train_state
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--steps", type=int, default=300)
     ap.add_argument("--smoke", action="store_true")

@@ -209,9 +209,9 @@ def odeint(
     ``use_pallas=True`` enables the fused flat-state fast path: the
     state pytree is raveled once per solve and every ψ trial (stage
     increments, solution/error combine, scaled error norm) runs as
-    fused Pallas kernels — compiled on TPU, interpret-mode elsewhere
-    (``repro.kernels.ops.set_interpret`` / REPRO_PALLAS_INTERPRET
-    override).  The fused step computes the same f32 arithmetic in the
+    fused Pallas kernels — always compiled on TPU, interpret-mode
+    elsewhere (where ``repro.kernels.ops.set_interpret`` /
+    REPRO_PALLAS_INTERPRET override the choice).  The fused step computes the same f32 arithmetic in the
     same accumulation order as the pytree path (bit-identical in the
     tested configurations; only the error-norm reduction is tiled, so a
     trial whose scaled error sits within ~1 ulp of the accept threshold

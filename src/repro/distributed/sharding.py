@@ -245,24 +245,26 @@ def shard_mesh(devices: Optional[Sequence] = None) -> Mesh:
     so importing this module touches no jax device state.
     """
     devices = list(devices if devices is not None else jax.devices())
-    return jax.make_mesh((len(devices),), ("data",), devices=devices)
+    return auto_mesh((len(devices),), ("data",), devices)
+
+
+def auto_mesh(shape: Tuple[int, ...], axis_names: Tuple[str, ...],
+              devices: Optional[Sequence] = None) -> Mesh:
+    """``jax.make_mesh`` with every axis ``Auto``: the repo's sharding
+    goes through ``with_sharding_constraint`` and GSPMD propagation,
+    which ``Explicit`` axes (the ``make_mesh`` default) reject."""
+    return jax.make_mesh(
+        shape, axis_names, devices=devices,
+        axis_types=(jax.sharding.AxisType.Auto,) * len(axis_names))
 
 
 def shard_map_compat(fn, *, mesh: Mesh, in_specs, out_specs):
-    """``shard_map`` across jax versions.
-
-    Newer jax exposes ``jax.shard_map(..., check_vma=...)``; the pinned
-    0.4.x line only has ``jax.experimental.shard_map.shard_map(...,
-    check_rep=...)``.  Replication checking is disabled either way: the
-    solver bodies run custom_vjp interiors the checker cannot see
-    through, and the model shard_fns psum manually.
-    """
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    from jax.experimental.shard_map import shard_map
-    return shard_map(fn, mesh=mesh, in_specs=in_specs,
-                     out_specs=out_specs, check_rep=False)
+    """``jax.shard_map`` with replication checking disabled — the one
+    shard_map call site of the repo: the solver bodies run custom_vjp
+    interiors the checker cannot see through, and the model shard_fns
+    psum manually."""
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 def model_axis_size(mesh: Optional[Mesh]) -> int:

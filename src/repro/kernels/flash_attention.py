@@ -27,10 +27,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-try:
-    from jax.experimental.pallas import tpu as pltpu
-except Exception:  # pragma: no cover
-    pltpu = None
+from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
 
@@ -123,8 +120,6 @@ def flash_attention_pallas(
         pltpu.VMEM((block_q, 1), jnp.float32),
         pltpu.VMEM((block_q, 1), jnp.float32),
         pltpu.VMEM((block_q, dh), jnp.float32),
-    ] if pltpu is not None else [
-        pl.MemorySpace.ANY((block_q, 1), jnp.float32),  # pragma: no cover
     ]
 
     return pl.pallas_call(

@@ -1,10 +1,11 @@
 """jit'd dispatch layer over the Pallas kernels.
 
-On a TPU backend the compiled kernels run natively; elsewhere (this
-container) ``interpret=True`` executes the kernel body in Python on CPU
+On a TPU backend the compiled kernels run natively, always; elsewhere
+``interpret=True`` executes the kernel body as plain XLA ops on the CPU
 — the mode the test suite validates against the ``ref.py`` oracles.
-``set_interpret(True)`` (or the REPRO_PALLAS_INTERPRET env var) forces
-interpret mode explicitly.
+Off the chip, ``set_interpret`` (or the REPRO_PALLAS_INTERPRET env var)
+overrides the choice; on the chip both are ignored, so a kernel run
+there can never be the interpreter in disguise.
 """
 
 from __future__ import annotations
@@ -46,6 +47,10 @@ def set_interpret(value: Optional[bool]) -> None:
 
 
 def _interpret() -> bool:
+    # on the chip the kernels always compile: an override there would
+    # silently time the interpreter instead of the kernels
+    if jax.default_backend() == "tpu":
+        return False
     if _FORCE_INTERPRET is not None:
         return _FORCE_INTERPRET
     env = os.environ.get("REPRO_PALLAS_INTERPRET")

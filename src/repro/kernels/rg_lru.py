@@ -25,10 +25,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-try:
-    from jax.experimental.pallas import tpu as pltpu
-except Exception:  # pragma: no cover
-    pltpu = None
+from jax.experimental.pallas import tpu as pltpu
 
 
 def _kernel(loga_ref, b_ref, y_ref, h_scr, *, q):
@@ -69,8 +66,7 @@ def rg_lru_pallas(
     nc = s // chunk
 
     grid = (bsz, c // c_tile, nc)
-    scratch = [pltpu.VMEM((1, c_tile), jnp.float32)] \
-        if pltpu is not None else []
+    scratch = [pltpu.VMEM((1, c_tile), jnp.float32)]
 
     return pl.pallas_call(
         functools.partial(_kernel, q=chunk),

@@ -25,10 +25,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-try:
-    from jax.experimental.pallas import tpu as pltpu
-except Exception:  # pragma: no cover
-    pltpu = None
+from jax.experimental.pallas import tpu as pltpu
 
 
 def _segsum_exp(da: jnp.ndarray, q: int) -> jnp.ndarray:
@@ -111,7 +108,7 @@ def ssd_scan_pallas(
         .reshape(bsz, h, nc, chunk, n)
 
     grid = (bsz, h, nc)
-    scratch = [pltpu.VMEM((p, n), jnp.float32)] if pltpu is not None else []
+    scratch = [pltpu.VMEM((p, n), jnp.float32)]
 
     y = pl.pallas_call(
         functools.partial(_kernel, q=chunk),

@@ -24,11 +24,13 @@ from typing import Optional, Sequence, Tuple
 
 import jax
 
+from repro.distributed.sharding import auto_mesh
+
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return auto_mesh(shape, axes)
 
 
 def elastic_mesh_shape(n_devices: int,
@@ -72,10 +74,9 @@ def make_elastic_mesh(devices: Optional[Sequence] = None,
     count — see ``elastic_mesh_shape``."""
     devices = list(devices if devices is not None else jax.devices())
     shape = elastic_mesh_shape(len(devices), model_parallel)
-    return jax.make_mesh(shape, ("pod", "data", "model"),
-                         devices=devices)
+    return auto_mesh(shape, ("pod", "data", "model"), devices)
 
 
 def make_debug_mesh(n_data: int = 1, n_model: int = 1):
     """Tiny mesh for CPU tests (requires >= n_data*n_model devices)."""
-    return jax.make_mesh((n_data, n_model), ("data", "model"))
+    return auto_mesh((n_data, n_model), ("data", "model"))

@@ -12,12 +12,14 @@ import time
 import jax
 import jax.numpy as jnp
 
+from repro.compile_cache import enable_compile_cache
 from repro.configs import get_config, get_smoke_config
 from repro.models import RunConfig, build_model
 from repro.serve import ServeConfig, ServeEngine
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--smoke", action="store_true")

@@ -17,6 +17,7 @@ import argparse
 import jax
 import jax.numpy as jnp
 
+from repro.compile_cache import enable_compile_cache
 from repro.configs import get_config, get_smoke_config
 from repro.core.node_block import NodeConfig
 from repro.data import TokenPipeline
@@ -28,6 +29,7 @@ from repro.train import TrainLoop, TrainLoopConfig, make_train_state
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--smoke", action="store_true")

@@ -21,11 +21,12 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
+from repro.launch.mesh import make_debug_mesh
 from repro.models import ModelConfig, RunConfig, build_model
 from conftest import tiny_batch
 
 assert len(jax.devices()) == 8
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = make_debug_mesh(2, 4)
 
 CONFIGS = [
     ModelConfig(name="dense", family="dense", n_layers=2, d_model=64,
