@@ -1,0 +1,22 @@
+"""Published peaks of one chip, keyed by JAX's ``device_kind``."""
+
+from __future__ import annotations
+
+import json
+import os
+
+_TABLE = os.path.join(os.path.dirname(__file__), "peaks.json")
+
+
+class UnknownDevice(KeyError):
+    """The device kind has no row in the peaks table."""
+
+
+def peaks(device_kind: str) -> dict:
+    with open(_TABLE) as fh:
+        table = json.load(fh)
+    if device_kind not in table:
+        raise UnknownDevice(
+            f"no peaks for device kind {device_kind!r}; known: "
+            f"{sorted(table)}")
+    return table[device_kind]

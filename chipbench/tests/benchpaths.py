@@ -1,0 +1,12 @@
+"""Puts the benchmark's directory and the program's ``src`` on the path
+for the tests here (imported first by each test module; a
+``conftest.py`` here would shadow the repo tests' own)."""
+
+import os
+import sys
+
+BENCH = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+ROOT = os.path.dirname(BENCH)
+for p in (os.path.join(ROOT, "src"), BENCH):
+    if p not in sys.path:
+        sys.path.insert(0, p)
