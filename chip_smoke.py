@@ -25,9 +25,11 @@ Phases (one chip):
   built as ``examples/train_node_lm.py`` builds it (seq 128, batch 8),
   3 steps after a warm-up step.  Losses finite, no step skipped, step-0
   loss within ``TRAIN_LOSS_REL`` of a ``use_pallas=False`` build, the
-  compiled step holds ``tpu_custom_call`` (the kernels really run), and
-  the layer-0 NODE block solved alone reports status OK with
-  ``n_steps < max_steps`` (the block itself drops its ``SolveStats``).
+  compiled step holds ``tpu_custom_call`` (the kernels really run), every
+  NODE block of every step reports status OK with ``n_steps < max_steps``
+  (the step's per-layer ``node_stats``), and the layer-0 NODE block
+  solved alone matches its ``use_pallas=False`` solve within
+  ``BLOCK_REL``.
 * **serve** — ``NodeServeEngine`` on the row-tolerance kernel (8 slots,
   dim 256) answers 16 seeded requests at mixed tolerances, each within
   the chunked-parity bound of ``docs/serving.md`` of a one-shot
@@ -38,9 +40,8 @@ Phases (one chip):
 device 0: identical per-element trial counts, ``ys`` and z0-grads
 bit-equal (``docs/distributed.md``), and a straggler count per shard.
 
-Earlier lines report the device, per-phase compile seconds and
-persistent-cache hits, and step times.  They are one smoke run, not a
-benchmark.  The last line is the JSON verdict
+Earlier lines report the device and what each phase checked; times are
+the benchmark's (``chipbench/``).  The last line is the JSON verdict
 ``{"ok": true, "device": {"platform": ..., "kind": ..., "count": ...}}``.
 """
 
@@ -52,7 +53,6 @@ import functools
 import json
 import os
 import sys
-import time
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")  # no libtpu logs in /tmp
 
@@ -103,35 +103,6 @@ def require_tpu(count: int):
     if len(devices) < count:
         raise NoTPUError(f"needs {count} TPU chips, found {len(devices)}")
     return devices
-
-
-class CompileMeter:
-    """Backend compile seconds and persistent-cache hits, per phase."""
-
-    def __init__(self):
-        self.secs = 0.0
-        self.requests = 0
-        self.hits = 0
-        jax.monitoring.register_event_duration_secs_listener(self._duration)
-        jax.monitoring.register_event_listener(self._event)
-
-    def _duration(self, event, duration, **_):
-        if event == "/jax/core/compile/backend_compile_duration":
-            self.secs += duration
-
-    def _event(self, event, **_):
-        if event == "/jax/compilation_cache/compile_requests_use_cache":
-            self.requests += 1
-        elif event == "/jax/compilation_cache/cache_hits":
-            self.hits += 1
-
-    def snapshot(self):
-        return self.secs, self.requests, self.hits
-
-    def report(self, phase, since, wall):
-        secs, req, hits = (a - b for a, b in zip(self.snapshot(), since))
-        print(f"phase {phase}: ok  wall_s={wall:.2f}  compile_s={secs:.2f}  "
-              f"cache_hits={hits}/{req}", flush=True)
 
 
 def rel_err(a, ref) -> float:
@@ -311,12 +282,10 @@ def train_phase():
             grad_method=node.grad_method, rtol=node.rtol, atol=node.atol,
             max_steps=node.max_steps, use_pallas=pallas,
             checkpoint_segments=node.checkpoint_segments))(x0, p0)
-        status, n_steps = int(st.status), int(st.n_steps)
         print(f"  layer-0 block ({'pallas' if pallas else 'pytree'}): "
-              f"status {SolveStatus.describe(status)}, n_steps {n_steps}/"
-              f"{node.max_steps}, trials {int(st.n_trials)}", flush=True)
-        check(status == SolveStatus.OK and n_steps < node.max_steps,
-              f"layer-0 NODE block: status {status}, n_steps {n_steps}")
+              f"status {SolveStatus.describe(int(st.status))}, n_steps "
+              f"{int(st.n_steps)}/{node.max_steps}, trials "
+              f"{int(st.n_trials)}", flush=True)
         block_out[pallas] = z1
     e_block = rel_err(block_out[True], block_out[False])
     print(f"  layer-0 block pallas vs pytree rel err {e_block:.3e}",
@@ -324,35 +293,31 @@ def train_phase():
     check(e_block <= BLOCK_REL, f"layer-0 block off by {e_block:.3e} rel")
 
     loop = TrainLoop(model, opt, lcfg, state)
-    t0 = time.perf_counter()
     # the private jitted step is the one the loop runs: its compiled text
     # shows whether the kernels survived into the program
     hlo = loop._step_fn.lower(loop.state, batch0,
                               loop.comp_state).compile().as_text()
     n_kernels = hlo.count("tpu_custom_call")
-    print(f"  train step lower+compile {time.perf_counter() - t0:.2f}s, "
-          f"tpu_custom_call x{n_kernels}", flush=True)
+    print(f"  train step: tpu_custom_call x{n_kernels}", flush=True)
     check(n_kernels > 0, "compiled train step holds no tpu_custom_call")
 
     metrics = []
-    log = lambda s, m: metrics.append(m)  # noqa: E731
-    t0 = time.perf_counter()
-    loop.run(pipe.batch, 1, log_cb=log)
-    print(f"  warm-up step {time.perf_counter() - t0:.2f}s", flush=True)
-    times = []
-    for _ in range(TRAIN_STEPS):
-        t0 = time.perf_counter()
-        loop.run(pipe.batch, loop.step + 1, log_cb=log)
-        times.append(time.perf_counter() - t0)
+    loop.run(pipe.batch, 1 + TRAIN_STEPS,
+             log_cb=lambda s, m: metrics.append(m))
     losses = [m["loss"] for m in metrics]
-    print(f"  step seconds {['%.4f' % t for t in times]}, losses "
-          f"{['%.4f' % v for v in losses]}, step-0 pytree loss "
-          f"{ref_loss:.4f}", flush=True)
+    nfe = [m["node_stats"].nfe for m in metrics]
+    print(f"  losses {['%.4f' % v for v in losses]}, step-0 pytree loss "
+          f"{ref_loss:.4f}; field evaluations per block {nfe}", flush=True)
     check(all(np.isfinite(losses)), f"non-finite loss {losses}")
     check(loop.skipped_steps == 0 and not any(m["skipped"] for m in metrics),
           f"{loop.skipped_steps} step(s) skipped")
     check(abs(losses[0] - ref_loss) <= TRAIN_LOSS_REL * abs(ref_loss),
           f"step-0 loss {losses[0]:.5f} vs pytree build {ref_loss:.5f}")
+    for m in metrics:
+        st = m["node_stats"]
+        check(all(s == SolveStatus.OK for s in st.status)
+              and max(st.n_steps) < node.max_steps,
+              f"NODE blocks: status {st.status}, n_steps {st.n_steps}")
 
 
 # ------------------------------------------------------------------- serve
@@ -381,10 +346,8 @@ def serve_phase():
         slots=SERVE_SLOTS, chunk_dt=0.5, use_pallas=True))
     for arrival, req in traffic:
         eng.submit(req, arrival=arrival)
-    t0 = time.perf_counter()
     results = {r.req_id: r for r in eng.run()}
-    print(f"  served {len(results)} requests in {eng.round} rounds, "
-          f"{time.perf_counter() - t0:.2f}s host time (compile included)",
+    print(f"  served {len(results)} requests in {eng.round} rounds",
           flush=True)
     check(len(results) == SERVE_REQUESTS and all(
         r.ok for r in results.values()),
@@ -424,19 +387,13 @@ def sharded_phase(devices):
                         mesh=mesh, **kw)
         return jnp.sum(ys[-1] ** 2), (ys, st)
 
-    def run(fn, *xs):
-        out = jax.block_until_ready(fn(*xs))      # compile + first run
-        t0 = time.perf_counter()
-        jax.block_until_ready(fn(*xs))
-        return jax.device_get(out), time.perf_counter() - t0
-
     grad = functools.partial(jax.value_and_grad, argnums=0, has_aux=True)
     mesh = shard_mesh(devices[:SHARDED_CHIPS])
-    sharded, t_sh = run(jax.jit(grad(functools.partial(loss, mesh=mesh))),
-                        z0, w)
+    sharded = jax.device_get(
+        jax.jit(grad(functools.partial(loss, mesh=mesh)))(z0, w))
     one = jax.device_put((z0, w), devices[0])
-    unsharded, t_un = run(jax.jit(grad(functools.partial(loss, mesh=None))),
-                          *one)
+    unsharded = jax.device_get(
+        jax.jit(grad(functools.partial(loss, mesh=None)))(*one))
     (_, (ys_s, st_s)), g_s = sharded
     (_, (ys_u, st_u)), g_u = unsharded
     check_status("sharded", st_s)
@@ -446,8 +403,6 @@ def sharded_phase(devices):
     print(f"  per-shard straggler trials {per_shard.tolist()} (global "
           f"{int(trials.max())}, median element "
           f"{int(np.median(trials))})", flush=True)
-    print(f"  solve+grad seconds: sharded over {SHARDED_CHIPS} chips "
-          f"{t_sh:.4f}, unsharded on one {t_un:.4f}", flush=True)
     check(np.array_equal(trials, np.asarray(st_u.n_trials)),
           "per-element trial counts differ sharded vs unsharded")
     check(len(set(per_shard.tolist())) > 1,
@@ -477,18 +432,13 @@ def main():
     dev = devices[0]
     print(f"device: {dev.platform} {dev.device_kind} x{len(devices)}; "
           f"jax {jax.__version__}; compile cache {cache}", flush=True)
-    print("single smoke run: one sample per time, not a benchmark",
-          flush=True)
-
-    meter = CompileMeter()
     phases = ([("sharded", functools.partial(sharded_phase, devices))]
               if args.four_chips else
               [("solve", solve_phase), ("train", train_phase),
                ("serve", serve_phase)])
     for name, phase in phases:
-        since, t0 = meter.snapshot(), time.perf_counter()
         phase()
-        meter.report(name, since, time.perf_counter() - t0)
+        print(f"phase {name}: ok", flush=True)
     print(json.dumps({"ok": True, "device": {
         "platform": dev.platform, "kind": dev.device_kind,
         "count": len(devices)}}))

@@ -57,8 +57,9 @@ def block_fn(p, z, t):
 
 
 z = jax.random.normal(jax.random.PRNGKey(2), (4, 8))
-zT = node_block_apply(block_fn, params, z,
-                      NodeConfig(enabled=True, solver="heun_euler",
-                                 grad_method="aca"))
+zT, stats = node_block_apply(block_fn, params, z,
+                             NodeConfig(enabled=True, solver="heun_euler",
+                                        grad_method="aca"))
 print("\nNODE block: in", z.shape, "-> out", zT.shape,
-      "| param count unchanged:", sum(p.size for p in params.values()))
+      "| param count unchanged:", sum(p.size for p in params.values()),
+      "| field evaluations:", int(stats.nfe))

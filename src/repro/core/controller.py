@@ -19,6 +19,8 @@ from typing import Any, Tuple
 import jax
 import jax.numpy as jnp
 
+from .stepper import field_eval
+
 
 @dataclasses.dataclass(frozen=True)
 class ControllerConfig:
@@ -65,13 +67,13 @@ def initial_stepsize(f, t0, z0, args, order: int, rtol: float, atol: float):
     scale = jax.tree.map(
         lambda z: atol + rtol * jnp.abs(z), z0)
 
-    f0 = f(t0, z0, *args)
+    f0 = field_eval(f, t0, z0, *args)
     d0 = _norm(jax.tree.map(lambda z, s: z / s, z0, scale))
     d1 = _norm(jax.tree.map(lambda g, s: g / s, f0, scale))
     h0 = jnp.where((d0 < 1e-5) | (d1 < 1e-5), 1e-6, 0.01 * d0 / d1)
 
     z1 = jax.tree.map(lambda z, g: z + h0 * g, z0, f0)
-    f1 = f(t0 + h0, z1, *args)
+    f1 = field_eval(f, t0 + h0, z1, *args)
     d2 = _norm(jax.tree.map(lambda a, b, s: (a - b) / s, f1, f0, scale)) / h0
     dmax = jnp.maximum(d1, d2)
     # Hairer I.4 step (f): h1 = (0.01 / max(d1, d2))^(1/(p+1)) — the

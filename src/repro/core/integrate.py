@@ -64,6 +64,7 @@ from .stepper import (
     alf_step,
     alf_step_batched,
     error_ratio,
+    field_eval,
     interp_eval,
     interp_fit,
     lattice_decode,
@@ -75,6 +76,11 @@ from .stepper import (
 from .tableaus import Tableau
 
 PyTree = Any
+
+# named scope of the adaptive loops' trajectory-checkpoint writes (and of
+# the segmented ACA backward's replay-buffer writes): their ops carry it
+# in their HLO op_name
+CKPT_WRITE_SCOPE = "ode_ckpt_write"
 
 
 def _as_tuple(args) -> Tuple:
@@ -487,7 +493,7 @@ def adaptive_while_solve(
     ckpt_t, ckpt_h, ckpt_z, ckpt_oi = _init_checkpoint_buffers(
         z0, max_steps, tdt, n_snap)
 
-    k0 = f(ts[0], z0, *args)
+    k0 = field_eval(f, ts[0], z0, *args)
     nfe0 = jnp.asarray(1 + hinit_evals, jnp.int32)
 
     # a non-finite initial state / derivative / h0 fails before stepping
@@ -587,35 +593,38 @@ def adaptive_while_solve(
             k0_acc = res.k_last
             nfe_acc = nfe
         else:
-            k0_acc = f(t_new, res.z_next, *args)
+            k0_acc = field_eval(f, t_new, res.z_next, *args)
             nfe_acc = nfe + 1
 
         # --- on accept: write trajectory checkpoint (t_i, h_i, z_i) -------
         i = c["i"]
-        ckpt_t = c["ckpt_t"].at[i].set(jnp.where(accept, t, c["ckpt_t"][i]))
-        ckpt_h = c["ckpt_h"].at[i].set(jnp.where(accept, h_use, c["ckpt_h"][i]))
-        ckpt_k0 = None
-        if checkpoint_segments is None:
-            ckpt_z = jax.tree.map(
-                lambda b, v: b.at[i].set(jnp.where(accept, v, b[i])),
-                c["ckpt_z"], z)
-        else:
-            # segmented: snapshot (z, k0) only at segment boundaries
-            # (accepted step s * seg_len); c["k0"] is exactly the
-            # first-stage derivative this accepted trial consumed
-            s = jnp.minimum(i // seg_len, n_snap - 1)
-            snap = accept & (i % seg_len == 0)
-            ckpt_z = jax.tree.map(
-                lambda b, v: b.at[s].set(jnp.where(snap, v, b[s])),
-                c["ckpt_z"], z)
-            ckpt_k0 = jax.tree.map(
-                lambda b, v: b.at[s].set(jnp.where(snap, v, b[s])),
-                c["ckpt_k0"], c["k0"])
         final_idx = jnp.asarray(n_eval - 1, jnp.int32)
         oi_val = jnp.where(hit, final_idx if natural else c["eval_idx"],
                            jnp.asarray(-1, jnp.int32))
-        ckpt_oi = c["ckpt_oi"].at[i].set(
-            jnp.where(accept, oi_val, c["ckpt_oi"][i]))
+        with jax.named_scope(CKPT_WRITE_SCOPE):
+            ckpt_t = c["ckpt_t"].at[i].set(
+                jnp.where(accept, t, c["ckpt_t"][i]))
+            ckpt_h = c["ckpt_h"].at[i].set(
+                jnp.where(accept, h_use, c["ckpt_h"][i]))
+            ckpt_k0 = None
+            if checkpoint_segments is None:
+                ckpt_z = jax.tree.map(
+                    lambda b, v: b.at[i].set(jnp.where(accept, v, b[i])),
+                    c["ckpt_z"], z)
+            else:
+                # segmented: snapshot (z, k0) only at segment boundaries
+                # (accepted step s * seg_len); c["k0"] is exactly the
+                # first-stage derivative this accepted trial consumed
+                s = jnp.minimum(i // seg_len, n_snap - 1)
+                snap = accept & (i % seg_len == 0)
+                ckpt_z = jax.tree.map(
+                    lambda b, v: b.at[s].set(jnp.where(snap, v, b[s])),
+                    c["ckpt_z"], z)
+                ckpt_k0 = jax.tree.map(
+                    lambda b, v: b.at[s].set(jnp.where(snap, v, b[s])),
+                    c["ckpt_k0"], c["k0"])
+            ckpt_oi = c["ckpt_oi"].at[i].set(
+                jnp.where(accept, oi_val, c["ckpt_oi"][i]))
 
         # --- outputs ------------------------------------------------------
         extra = {}
@@ -624,18 +633,19 @@ def adaptive_while_solve(
                 ts, karr, tiny, t, t_new, h_use, accept, hit,
                 c["eval_idx"], c["ys"], z, res.z_next, res.k_first,
                 k0_acc, res.z_mid)
-            extra["ckpt_elo"] = c["ckpt_elo"].at[i].set(
-                jnp.where(accept, c["eval_idx"], c["ckpt_elo"][i]))
-            extra["ckpt_ehi"] = c["ckpt_ehi"].at[i].set(
-                jnp.where(accept, c["eval_idx"] + n_cov,
-                          c["ckpt_ehi"][i]))
-            if store_coeffs:
-                extra["ckpt_cf"] = InterpCoeffs(*(
-                    jax.tree.map(
-                        lambda b, v: b.at[i].set(jnp.where(accept, v,
-                                                           b[i])),
-                        cb, cv)
-                    for cb, cv in zip(c["ckpt_cf"], coeffs)))
+            with jax.named_scope(CKPT_WRITE_SCOPE):
+                extra["ckpt_elo"] = c["ckpt_elo"].at[i].set(
+                    jnp.where(accept, c["eval_idx"], c["ckpt_elo"][i]))
+                extra["ckpt_ehi"] = c["ckpt_ehi"].at[i].set(
+                    jnp.where(accept, c["eval_idx"] + n_cov,
+                              c["ckpt_ehi"][i]))
+                if store_coeffs:
+                    extra["ckpt_cf"] = InterpCoeffs(*(
+                        jax.tree.map(
+                            lambda b, v: b.at[i].set(jnp.where(accept, v,
+                                                               b[i])),
+                            cb, cv)
+                        for cb, cv in zip(c["ckpt_cf"], coeffs)))
         else:
             # --- on eval-time hit: record output --------------------------
             ys = jax.tree.map(
@@ -800,7 +810,7 @@ def batched_adaptive_while_solve(
     ckpt_t, ckpt_h, ckpt_z, ckpt_oi = _init_checkpoint_buffers(
         z0, max_steps, tdt, n_snap, batch_size=B)
 
-    fb0 = jax.vmap(lambda ti, zi: f(ti, zi, *targs))
+    fb0 = jax.vmap(lambda ti, zi: field_eval(f, ti, zi, *targs))
     k0 = fb0(jnp.full((B,), ts[0], tdt), z0)
     nfe0 = jnp.full((B,), 1 + hinit_evals, jnp.int32)
 
@@ -882,42 +892,44 @@ def batched_adaptive_while_solve(
             k0_acc = res.k_last
             nfe_acc = jnp.zeros((B,), jnp.int32)
         else:
-            k0_acc = jax.vmap(lambda ti, zi: f(ti, zi, *targs))(
-                t_new, res.z_next)
+            k0_acc = jax.vmap(
+                lambda ti, zi: field_eval(f, ti, zi, *targs))(
+                    t_new, res.z_next)
             nfe_acc = jnp.ones((B,), jnp.int32)
 
         # --- on accept: write each element's own checkpoint row ----------
         i_c = jnp.minimum(c["i"], max_steps - 1)
-        ckpt_t = c["ckpt_t"].at[rows, i_c].set(
-            jnp.where(accept, t, c["ckpt_t"][rows, i_c]))
-        ckpt_h = c["ckpt_h"].at[rows, i_c].set(
-            jnp.where(accept, h_use, c["ckpt_h"][rows, i_c]))
-        ckpt_k0 = None
-        if checkpoint_segments is None:
-            ckpt_z = jax.tree.map(
-                lambda b, v: b.at[rows, i_c].set(_bwhere(accept, v,
-                                                         b[rows, i_c])),
-                c["ckpt_z"], z)
-        else:
-            # segmented: each element snapshots (z, k0) at ITS OWN
-            # boundaries; c["k0"] rows are exactly the first-stage
-            # derivatives this accepted trial consumed
-            s = jnp.minimum(i_c // seg_len, n_snap - 1)       # (B,)
-            snap = accept & (i_c % seg_len == 0)
-            ckpt_z = jax.tree.map(
-                lambda b, v: b.at[rows, s].set(_bwhere(snap, v,
-                                                       b[rows, s])),
-                c["ckpt_z"], z)
-            ckpt_k0 = jax.tree.map(
-                lambda b, v: b.at[rows, s].set(_bwhere(snap, v,
-                                                       b[rows, s])),
-                c["ckpt_k0"], c["k0"])
         final_idx = jnp.asarray(n_eval - 1, jnp.int32)
         oi_val = jnp.where(hit,
                            final_idx if interpolate_ts else c["eval_idx"],
                            jnp.full((B,), -1, jnp.int32))
-        ckpt_oi = c["ckpt_oi"].at[rows, i_c].set(
-            jnp.where(accept, oi_val, c["ckpt_oi"][rows, i_c]))
+        with jax.named_scope(CKPT_WRITE_SCOPE):
+            ckpt_t = c["ckpt_t"].at[rows, i_c].set(
+                jnp.where(accept, t, c["ckpt_t"][rows, i_c]))
+            ckpt_h = c["ckpt_h"].at[rows, i_c].set(
+                jnp.where(accept, h_use, c["ckpt_h"][rows, i_c]))
+            ckpt_k0 = None
+            if checkpoint_segments is None:
+                ckpt_z = jax.tree.map(
+                    lambda b, v: b.at[rows, i_c].set(_bwhere(accept, v,
+                                                             b[rows, i_c])),
+                    c["ckpt_z"], z)
+            else:
+                # segmented: each element snapshots (z, k0) at ITS OWN
+                # boundaries; c["k0"] rows are exactly the first-stage
+                # derivatives this accepted trial consumed
+                s = jnp.minimum(i_c // seg_len, n_snap - 1)       # (B,)
+                snap = accept & (i_c % seg_len == 0)
+                ckpt_z = jax.tree.map(
+                    lambda b, v: b.at[rows, s].set(_bwhere(snap, v,
+                                                           b[rows, s])),
+                    c["ckpt_z"], z)
+                ckpt_k0 = jax.tree.map(
+                    lambda b, v: b.at[rows, s].set(_bwhere(snap, v,
+                                                           b[rows, s])),
+                    c["ckpt_k0"], c["k0"])
+            ckpt_oi = c["ckpt_oi"].at[rows, i_c].set(
+                jnp.where(accept, oi_val, c["ckpt_oi"][rows, i_c]))
 
         # --- outputs ------------------------------------------------------
         extra = {}
@@ -928,11 +940,13 @@ def batched_adaptive_while_solve(
                 ts, karr, tiny, rows, t, t_new, h_use, accept, hit,
                 c["eval_idx"], c["ys"], z, res.z_next, res.k_first,
                 k0_acc, res.z_mid)
-            extra["ckpt_elo"] = c["ckpt_elo"].at[rows, i_c].set(
-                jnp.where(accept, c["eval_idx"], c["ckpt_elo"][rows, i_c]))
-            extra["ckpt_ehi"] = c["ckpt_ehi"].at[rows, i_c].set(
-                jnp.where(accept, c["eval_idx"] + n_cov,
-                          c["ckpt_ehi"][rows, i_c]))
+            with jax.named_scope(CKPT_WRITE_SCOPE):
+                extra["ckpt_elo"] = c["ckpt_elo"].at[rows, i_c].set(
+                    jnp.where(accept, c["eval_idx"],
+                              c["ckpt_elo"][rows, i_c]))
+                extra["ckpt_ehi"] = c["ckpt_ehi"].at[rows, i_c].set(
+                    jnp.where(accept, c["eval_idx"] + n_cov,
+                              c["ckpt_ehi"][rows, i_c]))
         else:
             # --- on eval-time hit: record that element's output ----------
             e_c = jnp.minimum(c["eval_idx"], n_eval - 1)

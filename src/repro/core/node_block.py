@@ -20,7 +20,7 @@ For multi-pod lowering, NODE mode supports two regimes:
 from __future__ import annotations
 
 import dataclasses
-from typing import Any, Callable, Optional
+from typing import Any, Callable, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -92,10 +92,13 @@ def node_block_apply(
     params: PyTree,
     z0: PyTree,
     cfg: NodeConfig,
-) -> PyTree:
+) -> Tuple[PyTree, SolveStats]:
     """z(t1) = z(0) + ∫ f(z, t; θ) dt with ACA/adjoint/naive gradients.
 
     ``block_fn(params, z, t) -> dz/dt`` must preserve the shape/dtype of z.
+    Returns ``(z(t1), stats)``: the forward solve's ``SolveStats``
+    (accepted steps, trials, field evaluations, status), scalars — or
+    per-sample arrays with ``batch_axis`` set.
     """
 
     def f(t, z, p):
@@ -109,7 +112,7 @@ def node_block_apply(
             "naive for static pod-scale schedules")
 
     if cfg.regime == "fixed":
-        zT, _ = odeint_final(
+        return odeint_final(
             f, z0, cfg.t0, cfg.t1, (params,),
             solver=_fixed_solver_for(cfg.solver),
             grad_method=cfg.grad_method,
@@ -122,22 +125,20 @@ def node_block_apply(
             on_failure=cfg.on_failure,
             mesh=cfg.mesh, shard_rules=cfg.shard_rules,
         )
-    else:
-        zT, _ = odeint_final(
-            f, z0, cfg.t0, cfg.t1, (params,),
-            # mali pairs only with the ALF pair integrator; the RK
-            # solver name in the config is a don't-care for that method
-            solver="alf" if cfg.grad_method == "mali" else cfg.solver,
-            grad_method=cfg.grad_method,
-            rtol=cfg.rtol, atol=cfg.atol,
-            max_steps=cfg.max_steps,
-            use_pallas=cfg.use_pallas,
-            batch_axis=cfg.batch_axis,
-            checkpoint_segments=cfg.checkpoint_segments,
-            on_failure=cfg.on_failure,
-            mesh=cfg.mesh, shard_rules=cfg.shard_rules,
-        )
-    return zT
+    return odeint_final(
+        f, z0, cfg.t0, cfg.t1, (params,),
+        # mali pairs only with the ALF pair integrator; the RK
+        # solver name in the config is a don't-care for that method
+        solver="alf" if cfg.grad_method == "mali" else cfg.solver,
+        grad_method=cfg.grad_method,
+        rtol=cfg.rtol, atol=cfg.atol,
+        max_steps=cfg.max_steps,
+        use_pallas=cfg.use_pallas,
+        batch_axis=cfg.batch_axis,
+        checkpoint_segments=cfg.checkpoint_segments,
+        on_failure=cfg.on_failure,
+        mesh=cfg.mesh, shard_rules=cfg.shard_rules,
+    )
 
 
 def _fixed_solver_for(name: str) -> str:

@@ -51,6 +51,7 @@ import numpy as np
 
 from .controller import ControllerConfig
 from .integrate import (
+    CKPT_WRITE_SCOPE,
     Checkpoints,
     SolveStats,
     SolveStatus,
@@ -65,6 +66,7 @@ from .integrate import (
     resolve_segmentation,
 )
 from .stepper import (
+    field_eval,
     interp_eval,
     interp_fit,
     maybe_flatten,
@@ -75,6 +77,11 @@ from .stepper import (
 from .tableaus import Tableau
 
 PyTree = Any
+
+# named scope of the whole ACA backward sweep (segment re-integration,
+# reverse replay and its buffer reads): every op the custom_vjp's
+# backward rule builds carries it in its HLO op_name
+ACA_BACKWARD_SCOPE = "ode_aca_backward"
 
 
 def _local_step_dense(tab, f, t_i, h_i, z_i, a, ts, use_pallas):
@@ -94,7 +101,7 @@ def _local_step_dense(tab, f, t_i, h_i, z_i, a, ts, use_pallas):
     if tab.fsal:
         k1 = res.k_last
     else:
-        k1 = f(t_i + h_i, res.z_next, *targs)
+        k1 = field_eval(f, t_i + h_i, res.z_next, *targs)
     coeffs = interp_fit(z_i, res.z_next, res.k_first, k1, h_i, res.z_mid)
     tiny = jnp.asarray(jnp.finfo(ts.dtype).eps, ts.dtype)
     theta = jnp.clip((ts - t_i) / jnp.maximum(h_i, tiny), 0.0, 1.0)
@@ -249,13 +256,14 @@ def _aca_backward_sweep_segmented(
             z, k0, zbuf = zc
             i = i0 + q
             t_i, h_i = ckpts.t[i], ckpts.h[i]
-            zbuf = jax.tree.map(lambda b, v: b.at[q].set(v), zbuf, z)
+            with jax.named_scope(CKPT_WRITE_SCOPE):
+                zbuf = jax.tree.map(lambda b, v: b.at[q].set(v), zbuf, z)
             res = rk_step(tab, f, t_i, z, h_i, targs, k0=k0,
                           use_pallas=use_pallas)
             if tab.fsal:
                 k0_new = res.k_last
             else:
-                k0_new = f(t_i + h_i, res.z_next, *targs)
+                k0_new = field_eval(f, t_i + h_i, res.z_next, *targs)
             return (res.z_next, k0_new, zbuf)
 
         _, _, zbuf = jax.lax.fori_loop(
@@ -308,8 +316,8 @@ def _local_step_dense_batched(tab, f, t_i, h_i, z_i, a, ts, use_pallas):
     if tab.fsal:
         k1 = res.k_last
     else:
-        k1 = jax.vmap(lambda ti, zi: f(ti, zi, *targs))(t_i + h_i,
-                                                        res.z_next)
+        k1 = jax.vmap(lambda ti, zi: field_eval(f, ti, zi, *targs))(
+            t_i + h_i, res.z_next)
     coeffs = interp_fit(z_i, res.z_next, res.k_first, k1, h_i, res.z_mid)
     tiny = jnp.asarray(jnp.finfo(ts.dtype).eps, ts.dtype)
     theta = jnp.clip(
@@ -482,9 +490,10 @@ def _aca_backward_sweep_segmented_batched(
             h_i = jnp.where(live, ckpts.h[rows, i_c], jnp.zeros((), hdt))
             in_win = live & (i >= g_lo)
             slot = jnp.clip(i - g_lo, 0, seg_len - 1)
-            zbuf = jax.tree.map(
-                lambda b, v: b.at[rows, slot].set(
-                    _bwhere(in_win, v, b[rows, slot])), zbuf, z)
+            with jax.named_scope(CKPT_WRITE_SCOPE):
+                zbuf = jax.tree.map(
+                    lambda b, v: b.at[rows, slot].set(
+                        _bwhere(in_win, v, b[rows, slot])), zbuf, z)
             # h = 0 makes ψ the exact identity for rows outside their
             # window, so the carry stays bit-stable without extra masking
             res = rk_step_batched(tab, f, t_i, z, h_i, targs, k0=k0,
@@ -493,8 +502,8 @@ def _aca_backward_sweep_segmented_batched(
                 k0_new = res.k_last
             else:
                 k0_new = jax.vmap(
-                    lambda ti, zi: f(ti, zi, *targs))(t_i + h_i,
-                                                      res.z_next)
+                    lambda ti, zi: field_eval(f, ti, zi, *targs))(
+                        t_i + h_i, res.z_next)
             return (res.z_next, k0_new, zbuf)
 
         _, _, zbuf = jax.lax.fori_loop(0, 2 * seg_len, fwd_body,
@@ -611,6 +620,7 @@ def odeint_aca_batched(
             interpolate_ts=interpolate_ts)
         return (ys, stats), (ckpts, args, ts, stats.status)
 
+    @jax.named_scope(ACA_BACKWARD_SCOPE)
     def solve_bwd(res, cot):
         ckpts, args, ts, status = res
         g_ys, _g_stats = cot  # stats are integer outputs; cotangent ignored
@@ -704,6 +714,7 @@ def odeint_aca(
             interpolate_ts=interpolate_ts)
         return (ys, stats), (ckpts, args, ts, stats.status)
 
+    @jax.named_scope(ACA_BACKWARD_SCOPE)
     def solve_bwd(res, cot):
         ckpts, args, ts, status = res
         g_ys, _g_stats = cot  # stats are integer outputs; cotangent ignored
@@ -789,6 +800,7 @@ def odeint_aca_fixed(
         ys, z_ckpt = _fwd(z0, args, t_grid, h_grid)
         return ys, (z_ckpt, args, t_grid, h_grid)
 
+    @jax.named_scope(ACA_BACKWARD_SCOPE)
     def solve_bwd(res, g_ys):
         z_ckpt, args, t_grid, h_grid = res
         ckpts = Checkpoints(

@@ -52,6 +52,18 @@ PyTree = Any
 VecField = Callable[..., PyTree]  # f(t, z, *args) -> dz/dt
 
 
+# named scope of every vector-field evaluation of the RK solvers: the
+# field's ops carry it in their HLO op_name, and so do their transposes
+# when the ACA backward sweep differentiates a replayed step
+FIELD_SCOPE = "ode_field"
+
+
+def field_eval(f: VecField, t, z: PyTree, *args) -> PyTree:
+    """``f(t, z, *args)`` under the ``ode_field`` named scope."""
+    with jax.named_scope(FIELD_SCOPE):
+        return f(t, z, *args)
+
+
 def _tree_axpy(alpha, x: PyTree, y: PyTree) -> PyTree:
     """y + alpha * x elementwise over pytrees, preserving y's dtype
     (an f32 stepsize scalar must not upcast a bf16 model state)."""
@@ -150,11 +162,11 @@ def _rk_step_flat(
     # through kernels.ref -> repro.models -> repro.core
     from repro.kernels import ops
 
-    k0v = k0 if k0 is not None else f(t, z, *args)
+    k0v = k0 if k0 is not None else field_eval(f, t, z, *args)
     ks = jnp.zeros((tab.stages,) + z.shape, k0v.dtype).at[0].set(k0v)
     for i in range(1, tab.stages):
         zi = ops.rk_stage_increment(z, ks[:i], h, tab.a[i])
-        ks = ks.at[i].set(f(t + tab.c[i] * h, zi, *args))
+        ks = ks.at[i].set(field_eval(f, t + tab.c[i] * h, zi, *args))
 
     ratio = None
     if tab.b_err is not None and err_scale is not None:
@@ -221,12 +233,12 @@ def rk_step(
     ks = []
     for i in range(tab.stages):
         if i == 0:
-            ki = k0 if k0 is not None else f(t, z, *args)
+            ki = k0 if k0 is not None else field_eval(f, t, z, *args)
         else:
             zi = z
             incr = _weighted_sum(tuple(ks), tab.a[i])
             zi = _tree_axpy(h, incr, z)
-            ki = f(t + tab.c[i] * h, zi, *args)
+            ki = field_eval(f, t + tab.c[i] * h, zi, *args)
         ks.append(ki)
     ks = tuple(ks)
 
@@ -354,7 +366,7 @@ def rk_step_batched(
     fused kernels; other states take the vmapped pytree path.
     ``dense=True`` as in ``rk_step`` (per-row ``k_first`` / ``z_mid``).
     """
-    fb = jax.vmap(lambda ti, zi: f(ti, zi, *args))
+    fb = jax.vmap(lambda ti, zi: field_eval(f, ti, zi, *args))
     if use_pallas and _is_flat_batched(z):
         return _rk_step_flat_batched(tab, fb, t, z, h, k0, err_scale,
                                      dense=dense)

@@ -3,7 +3,9 @@
 ``build_model(cfg, rcfg)`` returns a ``Model`` facade with:
 
   * ``defs`` / ``init`` / ``abstract`` / ``specs`` — parameter tree,
-  * ``loss_fn(params, batch)``        — train-mode forward + CE loss,
+  * ``loss_fn(params, batch)``        — train-mode forward + CE loss
+    (its metrics hold ``node_stats``, the NODE blocks' per-layer
+    ``SolveStats``, when the stack runs NODE blocks),
   * ``prefill(params, batch)``        — forward returning per-layer caches,
   * ``decode_step(params, batch, caches)`` — one-token serve step,
   * ``cache_defs(batch, max_seq)``    — KV/state cache ParamDefs.
@@ -114,21 +116,28 @@ class Model:
                 caches: Optional[PyTree] = None,
                 positions: Optional[jnp.ndarray] = None
                 ) -> Tuple[jnp.ndarray, Optional[PyTree], jnp.ndarray]:
+        return self._forward(params, batch, mode, caches, positions)[:3]
+
+    def _forward(self, params, batch, mode, caches=None, positions=None):
+        """``forward`` plus the stack's NODE ``node_stats`` (or None)."""
         x = _embed(params, batch, self.cfg, self.rcfg)
-        y, new_caches, aux = stack_apply(
+        y, new_caches, aux, node_stats = stack_apply(
             params["stack"], x, self.cfg, self.rcfg, mode=mode,
             positions=positions, caches=caches)
         logits = _head(params, y, self.cfg, self.rcfg)
-        return logits, new_caches, aux
+        return logits, new_caches, aux, node_stats
 
     def loss_fn(self, params: PyTree, batch: Dict[str, jnp.ndarray]
                 ) -> Tuple[jnp.ndarray, Dict[str, jnp.ndarray]]:
-        logits, _, aux = self.forward(params, batch, mode="train")
+        logits, _, aux, node_stats = self._forward(params, batch, "train")
         loss, n = softmax_xent(logits, batch["labels"],
                                batch.get("mask"),
                                self.rcfg.label_smoothing)
         total = loss + self.cfg.router_aux_coef * aux
-        return total, {"ce_loss": loss, "aux_loss": aux, "tokens": n}
+        metrics = {"ce_loss": loss, "aux_loss": aux, "tokens": n}
+        if node_stats is not None:
+            metrics["node_stats"] = node_stats
+        return total, metrics
 
     # -- serving ---------------------------------------------------------
     def prefill(self, params: PyTree, batch: Dict[str, jnp.ndarray]

@@ -197,6 +197,8 @@ def stack_cache_defs(cfg: ModelConfig, batch: int, max_seq: int,
 
 
 def _apply_one(p, x, cfg, rcfg, kind, mode, positions, cache):
+    """One layer.  Returns (y, new_cache, aux_loss, node_stats), the
+    last the NODE block's ``SolveStats`` (None for a discrete block)."""
     if rcfg.node.enabled and mode == "train":
         # the paper: residual block -> ODE block, ACA gradients.
         # RunConfig.use_pallas turns on the fused flat-state solver path
@@ -204,12 +206,21 @@ def _apply_one(p, x, cfg, rcfg, kind, mode, positions, cache):
         ncfg = rcfg.node
         if rcfg.use_pallas and not ncfg.use_pallas:
             ncfg = dataclasses.replace(ncfg, use_pallas=True)
-        zT = node_block_apply(
+        zT, stats = node_block_apply(
             lambda pp, z, t: _branch_fn(pp, z, cfg, rcfg, kind, positions),
             p, x, ncfg)
-        return zT, None, jnp.zeros((), jnp.float32)
-    return block_apply(p, x, cfg, rcfg, kind, mode=mode,
-                       positions=positions, cache=cache)
+        return zT, None, jnp.zeros((), jnp.float32), stats
+    y, new_cache, aux = block_apply(p, x, cfg, rcfg, kind, mode=mode,
+                                    positions=positions, cache=cache)
+    return y, new_cache, aux, None
+
+
+def _stack_stats(stats: List[Optional[PyTree]]) -> Optional[PyTree]:
+    """Layer-stacked NODE stats (each leaf gains a leading layer axis),
+    or None where the layers are discrete blocks."""
+    if not stats or stats[0] is None:
+        return None
+    return jax.tree.map(lambda *xs: jnp.stack(xs), *stats)
 
 
 def stack_apply(
@@ -221,8 +232,11 @@ def stack_apply(
     mode: str = "train",
     positions: Optional[jnp.ndarray] = None,
     caches: Optional[PyTree] = None,
-) -> Tuple[jnp.ndarray, Optional[PyTree], jnp.ndarray]:
-    """Apply the full stack.  Returns (y, new_caches, aux_loss_sum)."""
+) -> Tuple[jnp.ndarray, Optional[PyTree], jnp.ndarray, Optional[PyTree]]:
+    """Apply the full stack.  Returns (y, new_caches, aux_loss_sum,
+    node_stats): ``node_stats`` is the NODE blocks' ``SolveStats`` with
+    a leading (n_layers,) axis in layer order, None when the stack runs
+    no NODE block (inference modes, discrete blocks)."""
     unit, n_groups, tail = stack_plan(cfg)
     need_cache = mode in ("prefill", "decode")
     aux_total = jnp.zeros((), jnp.float32)
@@ -232,15 +246,17 @@ def stack_apply(
         gp, gc = layer_in
         aux_g = jnp.zeros((), jnp.float32)
         outs = {}
+        stats = []
         for j, kind in enumerate(unit):
             key = f"u{j}_{kind}"
             c = gc.get(key) if gc is not None else None
-            x, nc, aux = _apply_one(gp[key], x, cfg, rcfg, kind, mode,
-                                    positions, c)
+            x, nc, aux, st = _apply_one(gp[key], x, cfg, rcfg, kind, mode,
+                                        positions, c)
             if need_cache:
                 outs[key] = nc
             aux_g = aux_g + aux
-        return x, (outs if need_cache else None, aux_g)
+            stats.append(st)
+        return x, (outs if need_cache else None, aux_g, _stack_stats(stats))
 
     group_params = {k: v for k, v in params.items() if k.startswith("u")}
     group_caches = None
@@ -252,7 +268,7 @@ def stack_apply(
         body = group_body
         if rcfg.remat == "block":
             body = jax.checkpoint(group_body)
-        x, (cache_out, aux_stack) = jax.lax.scan(
+        x, (cache_out, aux_stack, group_stats) = jax.lax.scan(
             body, x, (group_params,
                       group_caches if group_caches is not None
                       else _none_tree(group_params, n_groups)))
@@ -260,30 +276,40 @@ def stack_apply(
         if need_cache:
             new_caches.update(cache_out)
     else:
+        per_group = []
         for i in range(n_groups):
             gp = jax.tree.map(lambda v: v[i], group_params)
             gc = jax.tree.map(lambda v: v[i], group_caches) \
                 if group_caches is not None else None
-            x, (outs, aux_g) = group_body(x, (gp, gc))
+            x, (outs, aux_g, st) = group_body(x, (gp, gc))
             aux_total = aux_total + aux_g
+            per_group.append(st)
             if need_cache:
                 for k, v in outs.items():
                     new_caches.setdefault(k, []).append(v)
+        group_stats = _stack_stats(per_group)
         if need_cache and new_caches:
             new_caches = {
                 k: jax.tree.map(lambda *ls: jnp.stack(ls), *v)
                 for k, v in new_caches.items()}
 
+    # (n_groups, len(unit)) -> layer order, then the tail's layers
+    layer_stats = [] if group_stats is None else [jax.tree.map(
+        lambda v: v.reshape((-1,) + v.shape[2:]), group_stats)]
     for j, kind in enumerate(tail):
         key = f"tail{j}_{kind}"
         c = caches.get(key) if caches is not None else None
-        x, nc, aux = _apply_one(params[key], x, cfg, rcfg, kind, mode,
-                                positions, c)
+        x, nc, aux, st = _apply_one(params[key], x, cfg, rcfg, kind, mode,
+                                    positions, c)
         aux_total = aux_total + aux
         if need_cache:
             new_caches[key] = nc
+        if st is not None:
+            layer_stats.append(jax.tree.map(lambda v: v[None], st))
+    node_stats = jax.tree.map(lambda *xs: jnp.concatenate(xs),
+                              *layer_stats) if layer_stats else None
 
-    return x, (new_caches if need_cache else None), aux_total
+    return x, (new_caches if need_cache else None), aux_total, node_stats
 
 
 def _none_tree(group_params: PyTree, n: int):

@@ -17,11 +17,18 @@ Production posture:
   ``straggler_factor``× the EMA trip a callback (on a real cluster this
   feeds the controller that evicts/restarts the slow host — here it is
   surfaced in metrics and the hook is testable);
-* **donated** state buffers (in-place update under jit).
+* **donated** state buffers (in-place update under jit);
+* **phase spans**: each step's host phases — ``train/batch`` (the batch
+  function), ``train/dispatch`` (the jitted step call), ``train/wait``
+  (blocking on the loss and reading the skip flag back) and
+  ``train/ckpt_save`` — are ``jax.profiler`` host annotations, on the
+  device trace's clock when a profile is taken, and their seconds on
+  the loop's clock stay in ``last_phases`` for the last step run.
 """
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import time
 from functools import partial
@@ -29,6 +36,7 @@ from typing import Any, Callable, Dict, Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.models.lm import Model
 from repro.optim.adamw import Optimizer, apply_updates
@@ -151,6 +159,8 @@ class TrainLoop:
             self._step_fn = jax.jit(self._step_fn, donate_argnums=(0,))
         self.straggler_cb = straggler_cb
         self.skipped_steps = 0      # total non-finite updates skipped
+        # host seconds of each phase of the last step run, on ``clock``
+        self.last_phases: Dict[str, float] = {}
         self._ema_dt: Optional[float] = None
         self.manager = None
         if cfg.ckpt_dir:
@@ -164,6 +174,15 @@ class TrainLoop:
     def step(self) -> int:
         return int(self.state.step)
 
+    @contextlib.contextmanager
+    def _phase(self, name: str):
+        """Host span ``train/<name>`` in a profile, and its seconds on
+        the loop's clock in ``last_phases``."""
+        t0 = self._clock()
+        with jax.profiler.TraceAnnotation("train/" + name):
+            yield
+        self.last_phases[name] = self._clock() - t0
+
     def run(self, batch_fn: Callable[[int], Dict[str, jnp.ndarray]],
             n_steps: int,
             log_cb: Optional[Callable[[int, Dict], None]] = None):
@@ -171,14 +190,17 @@ class TrainLoop:
         metrics = {}
         while self.step < n_steps:
             s = self.step
-            batch = batch_fn(s)
-            t0 = self._clock()
-            self.state, self.comp_state, metrics = self._step_fn(
-                self.state, batch, self.comp_state)
-            jax.block_until_ready(metrics["loss"])
-            dt = self._clock() - t0
-            if "skipped" in metrics:
-                self.skipped_steps += int(metrics["skipped"])
+            self.last_phases = {}
+            with self._phase("batch"):
+                batch = batch_fn(s)
+            with self._phase("dispatch"):
+                self.state, self.comp_state, metrics = self._step_fn(
+                    self.state, batch, self.comp_state)
+            with self._phase("wait"):
+                jax.block_until_ready(metrics["loss"])
+                if "skipped" in metrics:
+                    self.skipped_steps += int(metrics["skipped"])
+            dt = self.last_phases["dispatch"] + self.last_phases["wait"]
 
             # straggler watch: EMA of step time, flag outliers
             if self._ema_dt is None:
@@ -190,8 +212,15 @@ class TrainLoop:
                 self._ema_dt = 0.9 * self._ema_dt + 0.1 * dt
 
             if self.manager and (s + 1) % self.cfg.ckpt_every == 0:
-                self.manager.save(s + 1, self.state)
+                with self._phase("ckpt_save"):
+                    self.manager.save(s + 1, self.state)
 
             if log_cb and (s + 1) % self.cfg.log_every == 0:
-                log_cb(s + 1, {k: float(v) for k, v in metrics.items()})
+                log_cb(s + 1, jax.tree.map(_to_host, metrics))
         return metrics
+
+
+def _to_host(v) -> Any:
+    """A metric for ``log_cb``: a float for a scalar, nested lists for
+    an array (the NODE blocks' per-layer ``node_stats``)."""
+    return float(v) if np.ndim(v) == 0 else np.asarray(v).tolist()

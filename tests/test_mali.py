@@ -430,10 +430,11 @@ def test_node_block_mali():
     z0 = jax.random.normal(jax.random.PRNGKey(1), (4, 8))
     cfg = NodeConfig(enabled=True, solver="alf", grad_method="mali",
                      rtol=1e-3, atol=1e-3, max_steps=256)
-    zT = node_block_apply(block_fn, p, z0, cfg)
+    zT, stats = node_block_apply(block_fn, p, z0, cfg)
     assert zT.shape == z0.shape and bool(jnp.all(jnp.isfinite(zT)))
+    assert int(stats.status) == 0 and int(stats.nfe) > 0
     g = jax.grad(lambda p: jnp.sum(
-        node_block_apply(block_fn, p, z0, cfg) ** 2))(p)
+        node_block_apply(block_fn, p, z0, cfg)[0] ** 2))(p)
     assert bool(jnp.all(jnp.isfinite(g)))
 
     with pytest.raises(ValueError, match="fixed"):

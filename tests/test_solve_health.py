@@ -254,8 +254,9 @@ def test_node_config_threads_on_failure():
     def block(p, z, t):
         return -p["w"] * z
 
-    zT = node_block_apply(block, params, jnp.ones((3,)), cfg)
+    zT, stats = node_block_apply(block, params, jnp.ones((3,)), cfg)
     assert bool(jnp.isfinite(zT).all())
+    assert int(stats.status) == SolveStatus.OK
 
 
 # ------------------------------------------------------------- fallback

@@ -156,17 +156,21 @@ def test_straggler_hook_fires():
     opt = adamw(cosine_warmup(1e-3, 5, 100))
     lcfg = TrainLoopConfig(straggler_factor=3.0)
     state = make_train_state(m, opt, jax.random.PRNGKey(0))
-    # injected clock: step 2 takes 31 fake-seconds (a straggler)
-    seq = [0.0, 1.0, 1.0, 2.0, 2.0, 33.0, 33.0, 34.0, 34.0, 35.0]
-    calls = [0]
+    # injected clock, read at each phase boundary of a step: every read
+    # once step 2's batch is made advances it 31 fake-seconds, the
+    # others 1 (step 2 is a straggler)
+    now, cur = [0.0], [0]
 
     def fake_clock():
-        i = calls[0]
-        calls[0] += 1
-        return seq[i] if i < len(seq) else seq[-1] + (i - len(seq)) + 1.0
+        now[0] += 31.0 if cur[0] == 2 else 1.0
+        return now[0]
+
+    def batch(s):
+        cur[0] = s
+        return pipe.batch(s)
 
     loop = TrainLoop(m, opt, lcfg, state, clock=fake_clock,
                      straggler_cb=lambda s, ratio: hits.append((s, ratio)))
-    loop.run(lambda s: pipe.batch(s), 5)
+    loop.run(batch, 5)
     assert hits, "straggler callback never fired"
     assert max(r for _, r in hits) > 5
