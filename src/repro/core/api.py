@@ -589,7 +589,7 @@ def _odeint_batched(
                     steps_per_interval=steps_per_interval,
                     use_pallas=use_pallas)
             b = jax.tree.leaves(z0)[0].shape[0]  # shard-local under mesh
-            stats = SolveStats(*(jnp.broadcast_to(s, (b,)) for s in stats))
+            stats = jax.tree.map(lambda s: jnp.broadcast_to(s, (b,)), stats)
         return ys, stats
 
     if mesh is None:

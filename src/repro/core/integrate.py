@@ -27,7 +27,10 @@ Two engines over the same Runge-Kutta stepper:
   through the stepper, an exact identity), so an element that has landed
   on its last ``ts[k]`` stops contributing f-evals to its ``SolveStats``
   and its buffers stay bit-stable while stragglers finish.  The loop
-  terminates when *all* elements are done.
+  terminates when *all* elements are done.  From a batch of twice
+  ``COMPACT_FLOOR`` rows up, it runs in halving phases: each time the
+  live rows fit half the block, they are gathered into a block of half
+  the size, so finished rows stop riding along.
 
 * ``fixed_grid_solve`` — ``lax.scan`` over a precomputed grid.  Fully
   differentiable (this is also the "naive" method for fixed-step solvers).
@@ -132,12 +135,19 @@ class SolveStats(NamedTuple):
     batched solve (``batch_axis``), where a finished element's counters
     stop advancing while stragglers integrate on.  ``status`` holds a
     ``SolveStatus`` code per solve/element — 0 (OK) on the healthy path.
+
+    ``n_rides`` (batched RK loop only) counts the iterations each row
+    spent in the block the loop processed, finished or not: Σ n_trials ÷
+    Σ n_rides is the share of processed row-slots that did a trial.
     """
     n_steps: jnp.ndarray      # accepted steps (paper's N_t)
     n_trials: jnp.ndarray     # total ψ trials (N_t * m)
     nfe: jnp.ndarray          # number of f evaluations
     overflow: jnp.ndarray     # bool: checkpoint buffer exhausted
     status: jnp.ndarray       # int32 SolveStatus code
+    # batched adaptive loop only: iterations in which the row held a
+    # slot of the processed block, live or frozen (None elsewhere)
+    n_rides: Optional[jnp.ndarray] = None
 
 
 class Checkpoints(NamedTuple):
@@ -391,7 +401,11 @@ def natural_grid_outputs_batched(ts, karr, tiny, rows, t, t_new, h_use,
                                  accept, hit, eval_idx, ys, z, z_next,
                                  k0, k1, z_mid):
     """Batched twin of ``natural_grid_outputs``: per-row times/steps,
-    (n_eval, B) cover mask, per-row ``n_cov``/``eval_advance``."""
+    (n_eval, B) cover mask, per-row ``n_cov``/``eval_advance``.
+
+    ``rows`` is None when the block is the whole batch in row order, or
+    the (B_p,) batch rows of a compacted block, which then index the
+    full-batch ``ys``."""
     n_eval = ts.shape[0]
     covered = (accept[None, :]
                & (karr[:, None] >= eval_idx[None, :])
@@ -404,10 +418,17 @@ def natural_grid_outputs_batched(ts, karr, tiny, rows, t, t_new, h_use,
         (ts[:, None] - t[None, :])
         / jnp.maximum(h_use, tiny)[None, :], 0.0, 1.0)
     yint = interp_eval(coeffs, theta)                   # (n_eval, B, ...)
-    ys = jax.tree.map(
-        lambda b, v: jnp.where(
-            covered.reshape(covered.shape + (1,) * (v.ndim - 2)), v, b),
-        ys, yint)
+
+    def cover(b, v):
+        return jnp.where(
+            covered.reshape(covered.shape + (1,) * (v.ndim - 2)), v, b)
+
+    if rows is None:
+        ys = jax.tree.map(cover, ys, yint)
+        rows = jnp.arange(t.shape[0])
+    else:
+        ys = jax.tree.map(
+            lambda b, v: b.at[:, rows].set(cover(b[:, rows], v)), ys, yint)
     ys = jax.tree.map(
         lambda b, v: b.at[n_eval - 1, rows].set(
             _bwhere(hit, v, b[n_eval - 1, rows])),
@@ -716,6 +737,21 @@ def _row_tolerances(rtol, atol, B):
             jnp.broadcast_to(jnp.asarray(atol, jnp.float32), (B,)))
 
 
+# smallest active block of the batched loop's row compaction: one row
+# group of the batched rk_stage kernels, and MXU-sized; a batch under
+# twice this runs as one loop over all of its rows
+COMPACT_FLOOR = 128
+
+
+def _block_sizes(B: int) -> list:
+    """Static row counts of the batched loop's phases: B, then halvings
+    down to ``COMPACT_FLOOR`` (just [B] below twice the floor)."""
+    sizes = [B]
+    while sizes[-1] // 2 >= COMPACT_FLOOR:
+        sizes.append(sizes[-1] // 2)
+    return sizes
+
+
 def _bwhere(pred, a, b):
     """jnp.where with a (B,) predicate broadcast over batch-leading leaves."""
     return jnp.where(pred.reshape((-1,) + (1,) * (a.ndim - 1)), a, b)
@@ -781,12 +817,21 @@ def batched_adaptive_while_solve(
     and per-trial error norm included), the per-request QoS knob of the
     serving engine.  A row at tolerance τ is bitwise the all-τ batch's
     row either way.
+
+    Row compaction: with B ≥ 2 · ``COMPACT_FLOOR`` the loop runs in
+    phases over an active block of B, B/2, B/4, … rows, down to the
+    floor.  A phase ends when its live rows fit the next block; the live
+    rows (in batch order, ``row_id``) and finished rows to fill it carry
+    their loop state into the next phase, and every buffer write indexes
+    the full-batch ``ys``/``Checkpoints`` by ``row_id``.  Each row takes
+    the same trials as in one loop over all B rows, so results are
+    bitwise the same per row; ``SolveStats.n_rides`` counts the
+    iterations each row held a slot of the block.
     """
     if not tab.adaptive:
         raise ValueError("batched_adaptive_while_solve requires an "
                          "embedded adaptive tableau")
     B = jax.tree.leaves(z0)[0].shape[0]
-    rows = jnp.arange(B)
     n_eval = ts.shape[0]
     tdt = ts.dtype
     max_steps = cfg.max_steps
@@ -818,7 +863,8 @@ def batched_adaptive_while_solve(
     failed0 = _nonfinite_rows((z0, k0, h0)) if guard_nonfinite \
         else jnp.zeros((B,), bool)
 
-    carry0 = dict(
+    # per-row loop state: a compacted phase carries its block's rows
+    state = dict(
         t=jnp.full((B,), ts[0], tdt), z=z0, k0=k0, h=h0,
         prev_ratio=jnp.ones((B,), jnp.float32),
         i=jnp.zeros((B,), jnp.int32),           # accepted steps so far
@@ -826,18 +872,23 @@ def batched_adaptive_while_solve(
         trials=jnp.zeros((B,), jnp.int32),
         nfe=nfe0,
         failed=failed0, uflow=jnp.zeros((B,), bool),
-        ys=ys, ckpt_t=ckpt_t, ckpt_h=ckpt_h, ckpt_z=ckpt_z, ckpt_oi=ckpt_oi,
+        rides=jnp.zeros((B,), jnp.int32),       # iterations in the block
     )
+    if row_tol is not None:
+        state["rtol"], state["atol"] = row_tol
+    # full-batch buffers, written by batch row in every phase
+    bufs = dict(ys=ys, ckpt_t=ckpt_t, ckpt_h=ckpt_h, ckpt_z=ckpt_z,
+                ckpt_oi=ckpt_oi)
     if checkpoint_segments is not None:
         # segmented replay re-chains FSAL reuse per element: snapshot
         # each element's k0 carry next to its state snapshots
-        carry0["ckpt_k0"] = jax.tree.map(
+        bufs["ckpt_k0"] = jax.tree.map(
             lambda l: jnp.zeros((l.shape[0], n_snap) + l.shape[1:],
                                 l.dtype), k0)
     if interpolate_ts:
         # per-element half-open eval-index ranges per accepted interval
-        carry0["ckpt_elo"] = jnp.zeros((B, max_steps), jnp.int32)
-        carry0["ckpt_ehi"] = jnp.zeros((B, max_steps), jnp.int32)
+        bufs["ckpt_elo"] = jnp.zeros((B, max_steps), jnp.int32)
+        bufs["ckpt_ehi"] = jnp.zeros((B, max_steps), jnp.int32)
 
     tiny = jnp.asarray(jnp.finfo(tdt).eps, tdt)
     karr = jnp.arange(n_eval)
@@ -850,11 +901,11 @@ def batched_adaptive_while_solve(
             & ~c["failed"]
         )
 
-    def cond(c):
-        return jnp.any(live_mask(c))
-
     def body(c):
         live = live_mask(c)
+        # a compacted block's batch rows (None: the whole batch in order)
+        rows = c.get("row_id")
+        idx = jnp.arange(live.shape[0]) if rows is None else rows
         t, z, h = c["t"], c["z"], c["h"]
         # natural grid: only the final time is a forced landing
         t_target = ts[n_eval - 1] if interpolate_ts else \
@@ -863,10 +914,11 @@ def batched_adaptive_while_solve(
         # dead elements step with h = 0: ψ degenerates to the identity
         h_use = jnp.where(live, jnp.clip(h, h_min, t_target - t),
                           jnp.zeros((), tdt))
-        res = rk_step_batched(tab, f, t, z, h_use, targs, k0=c["k0"],
-                              use_pallas=use_pallas,
-                              err_scale=(rtol, atol),
-                              dense=interpolate_ts)
+        res = rk_step_batched(
+            tab, f, t, z, h_use, targs, k0=c["k0"], use_pallas=use_pallas,
+            err_scale=(rtol, atol) if row_tol is None
+            else (c["rtol"], c["atol"]),
+            dense=interpolate_ts)
         ratio = res.err_ratio                                   # (B,)
         railed = h_use <= h_min * (1 + 1e-3)
         if guard_nonfinite:
@@ -875,7 +927,7 @@ def batched_adaptive_while_solve(
             bad = ~jnp.isfinite(ratio)
             accept = live & ((ratio <= 1.0) | railed) & ~bad
         else:
-            bad = jnp.zeros((B,), bool)
+            bad = jnp.zeros_like(live)
             accept = live & ((ratio <= 1.0) | railed)
         # per-element health flags (dead rows: live False masks them out)
         fail_now = live & bad & railed
@@ -890,29 +942,29 @@ def batched_adaptive_while_solve(
         # interval-end derivative of each element's interpolant)
         if tab.fsal:
             k0_acc = res.k_last
-            nfe_acc = jnp.zeros((B,), jnp.int32)
+            nfe_acc = jnp.zeros_like(c["nfe"])
         else:
             k0_acc = jax.vmap(
                 lambda ti, zi: field_eval(f, ti, zi, *targs))(
                     t_new, res.z_next)
-            nfe_acc = jnp.ones((B,), jnp.int32)
+            nfe_acc = jnp.ones_like(c["nfe"])
 
         # --- on accept: write each element's own checkpoint row ----------
         i_c = jnp.minimum(c["i"], max_steps - 1)
         final_idx = jnp.asarray(n_eval - 1, jnp.int32)
         oi_val = jnp.where(hit,
                            final_idx if interpolate_ts else c["eval_idx"],
-                           jnp.full((B,), -1, jnp.int32))
+                           jnp.full_like(c["eval_idx"], -1))
         with jax.named_scope(CKPT_WRITE_SCOPE):
-            ckpt_t = c["ckpt_t"].at[rows, i_c].set(
-                jnp.where(accept, t, c["ckpt_t"][rows, i_c]))
-            ckpt_h = c["ckpt_h"].at[rows, i_c].set(
-                jnp.where(accept, h_use, c["ckpt_h"][rows, i_c]))
+            ckpt_t = c["ckpt_t"].at[idx, i_c].set(
+                jnp.where(accept, t, c["ckpt_t"][idx, i_c]))
+            ckpt_h = c["ckpt_h"].at[idx, i_c].set(
+                jnp.where(accept, h_use, c["ckpt_h"][idx, i_c]))
             ckpt_k0 = None
             if checkpoint_segments is None:
                 ckpt_z = jax.tree.map(
-                    lambda b, v: b.at[rows, i_c].set(_bwhere(accept, v,
-                                                             b[rows, i_c])),
+                    lambda b, v: b.at[idx, i_c].set(_bwhere(accept, v,
+                                                            b[idx, i_c])),
                     c["ckpt_z"], z)
             else:
                 # segmented: each element snapshots (z, k0) at ITS OWN
@@ -921,15 +973,15 @@ def batched_adaptive_while_solve(
                 s = jnp.minimum(i_c // seg_len, n_snap - 1)       # (B,)
                 snap = accept & (i_c % seg_len == 0)
                 ckpt_z = jax.tree.map(
-                    lambda b, v: b.at[rows, s].set(_bwhere(snap, v,
-                                                           b[rows, s])),
+                    lambda b, v: b.at[idx, s].set(_bwhere(snap, v,
+                                                          b[idx, s])),
                     c["ckpt_z"], z)
                 ckpt_k0 = jax.tree.map(
-                    lambda b, v: b.at[rows, s].set(_bwhere(snap, v,
-                                                           b[rows, s])),
+                    lambda b, v: b.at[idx, s].set(_bwhere(snap, v,
+                                                          b[idx, s])),
                     c["ckpt_k0"], c["k0"])
-            ckpt_oi = c["ckpt_oi"].at[rows, i_c].set(
-                jnp.where(accept, oi_val, c["ckpt_oi"][rows, i_c]))
+            ckpt_oi = c["ckpt_oi"].at[idx, i_c].set(
+                jnp.where(accept, oi_val, c["ckpt_oi"][idx, i_c]))
 
         # --- outputs ------------------------------------------------------
         extra = {}
@@ -941,18 +993,18 @@ def batched_adaptive_while_solve(
                 c["eval_idx"], c["ys"], z, res.z_next, res.k_first,
                 k0_acc, res.z_mid)
             with jax.named_scope(CKPT_WRITE_SCOPE):
-                extra["ckpt_elo"] = c["ckpt_elo"].at[rows, i_c].set(
+                extra["ckpt_elo"] = c["ckpt_elo"].at[idx, i_c].set(
                     jnp.where(accept, c["eval_idx"],
-                              c["ckpt_elo"][rows, i_c]))
-                extra["ckpt_ehi"] = c["ckpt_ehi"].at[rows, i_c].set(
+                              c["ckpt_elo"][idx, i_c]))
+                extra["ckpt_ehi"] = c["ckpt_ehi"].at[idx, i_c].set(
                     jnp.where(accept, c["eval_idx"] + n_cov,
-                              c["ckpt_ehi"][rows, i_c]))
+                              c["ckpt_ehi"][idx, i_c]))
         else:
             # --- on eval-time hit: record that element's output ----------
             e_c = jnp.minimum(c["eval_idx"], n_eval - 1)
             ys = jax.tree.map(
-                lambda b, v: b.at[e_c, rows].set(
-                    _bwhere(hit, v, b[e_c, rows])),
+                lambda b, v: b.at[e_c, idx].set(
+                    _bwhere(hit, v, b[e_c, idx])),
                 c["ys"], res.z_next)
             eval_advance = hit.astype(jnp.int32)
 
@@ -971,6 +1023,7 @@ def batched_adaptive_while_solve(
             + jnp.where(accept, nfe_acc, 0)
 
         out = dict(
+            c,
             t=jnp.where(accept, t_new, t),
             z=_bwhere_tree(accept, res.z_next, z),
             k0=k0_new,
@@ -983,6 +1036,7 @@ def batched_adaptive_while_solve(
             nfe=nfe,
             failed=c["failed"] | fail_now,
             uflow=c["uflow"] | uflow_now,
+            rides=c["rides"] + 1,
             ys=ys, ckpt_t=ckpt_t, ckpt_h=ckpt_h, ckpt_z=ckpt_z,
             ckpt_oi=ckpt_oi,
         )
@@ -991,19 +1045,44 @@ def batched_adaptive_while_solve(
         out.update(extra)
         return out
 
-    c = jax.lax.while_loop(cond, body, carry0)
+    # Row compaction: phase p loops over a block of sizes[p] rows and
+    # ends once at most sizes[p + 1] of them are live; the next block
+    # gathers the live rows (batch order kept) and finished ones to fill
+    # it.  Rows never interact, so every row's trials are the same as in
+    # one loop over the whole batch.
+    sizes = _block_sizes(B)
+    for p, size in enumerate(sizes):
+        if p == 0:
+            row_id = None
+            carry = dict(state, **bufs)
+        else:
+            row_id = jnp.argsort((~live_mask(state)).astype(jnp.int32),
+                                 stable=True)[:size].astype(jnp.int32)
+            carry = dict(jax.tree.map(lambda x: x[row_id], state),
+                         row_id=row_id, **bufs)
+        n_next = sizes[p + 1] if p + 1 < len(sizes) else 0
 
+        def cond(c, n_next=n_next):
+            return jnp.sum(live_mask(c), dtype=jnp.int32) > n_next
+
+        c = jax.lax.while_loop(cond, body, carry)
+        bufs = {k: c[k] for k in bufs}
+        blk = {k: c[k] for k in state}
+        state = blk if row_id is None else jax.tree.map(
+            lambda full, b: full.at[row_id].set(b), state, blk)
+
+    c = state
     overflow = c["eval_idx"] < n_eval
     status = _compose_status(c["failed"], c["uflow"], ~overflow,
                              c["trials"] >= max_total_trials)
     fill = c["failed"][None, :] & (karr[:, None] >= c["eval_idx"][None, :])
-    ys_out = _freeze_fill(c["ys"], fill, c["z"])
-    ckpts = Checkpoints(t=c["ckpt_t"], h=c["ckpt_h"], z=c["ckpt_z"],
-                        out_idx=c["ckpt_oi"], n=c["i"],
-                        k0=c.get("ckpt_k0"),
-                        ev_lo=c.get("ckpt_elo"), ev_hi=c.get("ckpt_ehi"))
+    ys_out = _freeze_fill(bufs["ys"], fill, c["z"])
+    ckpts = Checkpoints(t=bufs["ckpt_t"], h=bufs["ckpt_h"], z=bufs["ckpt_z"],
+                        out_idx=bufs["ckpt_oi"], n=c["i"],
+                        k0=bufs.get("ckpt_k0"),
+                        ev_lo=bufs.get("ckpt_elo"), ev_hi=bufs.get("ckpt_ehi"))
     stats = SolveStats(n_steps=c["i"], n_trials=c["trials"], nfe=c["nfe"],
-                       overflow=overflow, status=status)
+                       overflow=overflow, status=status, n_rides=c["rides"])
     return ys_out, ckpts, stats
 
 

@@ -366,7 +366,7 @@ def odeint_naive_batched(
                 k1 = jax.vmap(lambda ti, zi: f(ti, zi, *targs))(
                     t_new, res.z_next)
             ys, _, _, eval_advance = natural_grid_outputs_batched(
-                ts, karr, tiny, rows, t, t_new, h_use, accept, hit,
+                ts, karr, tiny, None, t, t_new, h_use, accept, hit,
                 c["eval_idx"], c["ys"], z, res.z_next, res.k_first,
                 k1, res.z_mid)
         else:

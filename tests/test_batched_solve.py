@@ -20,7 +20,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core import GRAD_METHODS, odeint
+from repro.core import GRAD_METHODS, SolveStatus, odeint
 
 # dz/dt over z = [x (d-1,), logk (1,)]: per-sample stiffness exp(logk)
 # rides inside the state, so a shared-args batch can still be
@@ -51,6 +51,12 @@ def _kw(method):
     if method == "mali":
         return dict(solver=None, rtol=1e-5, atol=1e-5, max_steps=2048)
     return KW
+
+
+def _counted(st):
+    """Every ``SolveStats`` field but ``n_rides``, which counts a row's
+    slots in the processed block and so depends on the rows around it."""
+    return [v for k, v in st._asdict().items() if k != "n_rides"]
 
 
 @pytest.fixture
@@ -157,7 +163,7 @@ def test_finished_elements_freeze_bit_stable(method):
     assert int(np.asarray(st3.n_steps)[2]) > int(
         np.asarray(st3.n_steps)[:2].max())
     np.testing.assert_array_equal(np.asarray(ys2), np.asarray(ys3)[:, :2])
-    for a, b in zip(st2, st3):
+    for a, b in zip(_counted(st2), _counted(st3)):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b)[:2])
 
 
@@ -250,3 +256,151 @@ def test_per_element_overflow():
                       solver="dopri5", rtol=1e-7, atol=1e-7, max_steps=12)
     ov = np.asarray(stats.overflow)
     assert not ov[0] and ov[1], ov
+
+
+# ------------------------------------------------------ row compaction
+#
+# From 2 * COMPACT_FLOOR rows up the batched loop runs in halving phases
+# over a block of the rows still live.  A floor of 2 makes a 16-row batch
+# run every phase (16, 8, 4, 2); a floor above B keeps one loop.
+
+COMPACT_B = 16
+COMPACT_CASES = ["full", "segments", "interpolate", "rowtol", "pallas",
+                 "nonfinite"]
+
+
+def _shuffled_batch(B=COMPACT_B):
+    """Stiffness spread over the rows in shuffled order, so the live rows
+    of each compacted block are scattered across the batch."""
+    x0 = jax.random.normal(jax.random.PRNGKey(1), (B, 3))
+    logk = jax.random.permutation(jax.random.PRNGKey(2),
+                                  jnp.linspace(0.0, 3.5, B))
+    return jnp.concatenate([x0, logk[:, None]], axis=1).astype(jnp.float32)
+
+
+def _compaction_case(case):
+    """(field, ts, engine kwargs, odeint kwargs) of one case."""
+    f, ts = _f, TS
+    rtol = atol = 1e-5
+    eng, api = {}, {}
+    if case == "segments":
+        eng["checkpoint_segments"] = 4
+        api["checkpoint_segments"] = 4
+    elif case == "interpolate":
+        ts = jnp.linspace(0.0, 1.0, 6).astype(jnp.float32)
+        eng["interpolate_ts"] = api["interpolate_ts"] = True
+    elif case == "rowtol":
+        loose = jnp.arange(COMPACT_B) % 3 == 0
+        rtol = jnp.where(loose, 1e-3, 1e-5).astype(jnp.float32)
+        atol = jnp.where(loose, 1e-4, 1e-6).astype(jnp.float32)
+    elif case == "pallas":
+        eng["use_pallas"] = api["use_pallas"] = True
+    elif case == "nonfinite":
+        from faults import faulty_field
+        # the stiffest row meets a NaN field from t = 0.3 on and freezes
+        f = faulty_field(_f, "nan", t_ge=0.3,
+                         predicate=lambda t, z: z[-1] > 3.4)
+    return f, ts, rtol, atol, eng, api
+
+
+def _compaction_run(case, floor, monkeypatch):
+    from repro.core import ControllerConfig, get_tableau, integrate
+
+    monkeypatch.setattr(integrate, "COMPACT_FLOOR", floor)
+    f, ts, rtol, atol, eng, api = _compaction_case(case)
+    z0 = _shuffled_batch()
+    ys, ckpts, st = integrate.batched_adaptive_while_solve(
+        get_tableau("dopri5"), f, z0, ts, (W,), rtol, atol,
+        ControllerConfig(max_steps=64), **eng)
+
+    def loss(z0, w):
+        ys, st = odeint(f, z0, ts, (w,), grad_method="aca", batch_axis=0,
+                        solver="dopri5", rtol=rtol, atol=atol,
+                        max_steps=64, **api)
+        return jnp.sum(ys[-1] ** 2), (ys, st)
+
+    (_, (ys_api, st_api)), grads = jax.value_and_grad(
+        loss, argnums=(0, 1), has_aux=True)(z0, W)
+    return dict(ys=ys, ckpts=ckpts, stats=_counted(st), ys_api=ys_api,
+                stats_api=_counted(st_api), grads=grads), st
+
+
+@pytest.mark.parametrize("case", COMPACT_CASES)
+def test_compaction_is_bitwise(case, monkeypatch, _interpret_kernels):
+    """The compacted loop gives every row the outputs, checkpoints,
+    stats and ACA gradients of one loop over the whole batch, bit for
+    bit."""
+    one, st_one = _compaction_run(case, 10 * COMPACT_B, monkeypatch)
+    cmp, st_cmp = _compaction_run(case, 2, monkeypatch)
+    n_trials = np.asarray(st_one.n_trials)
+    assert len(np.unique(n_trials)) > 4, n_trials   # phases do work
+    assert (np.asarray(st_cmp.n_rides) < n_trials.max()).any()
+    status = np.asarray(st_one.status)
+    if case == "nonfinite":
+        assert (status == SolveStatus.NONFINITE_STATE).sum() == 1, status
+    else:
+        assert (status == SolveStatus.OK).all(), status
+    for key in one:
+        la, lb = jax.tree.leaves(one[key]), jax.tree.leaves(cmp[key])
+        assert len(la) == len(lb), key
+        for a, b in zip(la, lb):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b),
+                                          err_msg=key)
+
+
+def test_n_rides_counts_block_slots(monkeypatch):
+    """n_rides ≥ n_trials per row; one loop gives every row the trip
+    count; the compacted loop processes fewer row-slots."""
+    from repro.core import integrate
+
+    z0 = _shuffled_batch()
+
+    def solve(floor):
+        monkeypatch.setattr(integrate, "COMPACT_FLOOR", floor)
+        _, st = odeint(_f, z0, TS, (W,), grad_method="aca", batch_axis=0,
+                       **KW)
+        return np.asarray(st.n_trials), np.asarray(st.n_rides)
+
+    trials, rides_one = solve(10 * COMPACT_B)
+    trials_cmp, rides_cmp = solve(2)
+    np.testing.assert_array_equal(trials, trials_cmp)
+    # the straggler is live in every iteration: the trip count
+    np.testing.assert_array_equal(rides_one, trials.max())
+    assert (rides_one >= trials).all() and (rides_cmp >= trials).all()
+    assert rides_cmp.sum() < rides_one.sum()
+    assert trials.sum() / rides_cmp.sum() > trials.sum() / rides_one.sum()
+
+
+def _count_while_loops(jaxpr) -> int:
+    """``while`` equations in a jaxpr and every jaxpr nested in it."""
+    n = 0
+    for eqn in jaxpr.eqns:
+        n += eqn.primitive.name == "while"
+        for v in eqn.params.values():
+            for sub in (v if isinstance(v, (list, tuple)) else [v]):
+                inner = getattr(sub, "jaxpr", sub)
+                if hasattr(inner, "eqns"):
+                    n += _count_while_loops(inner)
+    return n
+
+
+@pytest.mark.parametrize("slots", [8, 512])
+def test_serve_slots_keep_one_loop(slots):
+    """The solve server's 8 slots stay under 2 * COMPACT_FLOOR and trace
+    today's single loop; 512 slots trace one loop per phase."""
+    from repro.core import integrate
+    from repro.serve.node_engine import NodeEngineConfig, NodeServeEngine
+
+    def f(t, z, w):
+        return -w * z
+
+    eng = NodeServeEngine(f, 3, (jnp.float32(1.0),),
+                          NodeEngineConfig(slots=slots))
+    row = jnp.zeros((slots,), jnp.float32)
+    jaxpr = jax.make_jaxpr(eng._solve)(
+        jnp.zeros((slots, 3 + 2), jnp.float32), row + 1e-5, row + 1e-5,
+        row + 0.1)
+    want = 1 if slots < 2 * integrate.COMPACT_FLOOR else \
+        len(integrate._block_sizes(slots))
+    assert want == (1 if slots == 8 else 3)
+    assert _count_while_loops(jaxpr.jaxpr) == want
