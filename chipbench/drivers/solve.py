@@ -6,11 +6,17 @@ of ``batches`` batches of ``rows`` rows made on the device from the
 seed (calls alternate over them).  The window keeps ``IN_FLIGHT`` calls
 queued behind the one it waits for.
 
+On several chips the solve runs on their mesh (``odeint(mesh=...)``
+over ``repro.distributed.shard_mesh``): each batch is made on the
+device already split by rows over the mesh's ``"data"`` axis, with the
+coupling replicated, so that a call moves no input between chips.
+
 Correct: after the window, the last answer of every batch (every row's
 final state and z0-gradient, and the coupling's gradient) is compared
 with the plain reference (``harness/ref_ode.py``) solving the same rows
-from the same seed; the control is that reference computed at the next
-lower matmul precision.
+from the same seed, in blocks of ``ref_block_rows`` rows spread over
+the cell's chips in turn; the control is that reference computed at the
+next lower matmul precision.
 """
 
 from __future__ import annotations
@@ -22,6 +28,7 @@ import time
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec
 
 from harness import counts, ref_ode, seeds
 from harness.runner import BENCH_DIR, Check, load_module, span
@@ -48,19 +55,28 @@ class Driver:
             BENCH_DIR, "configs", config["name"] + ".py"))
         self.rows = int(traffic["rows"])
         self.n_batches = int(traffic.get("batches", 1))
+        self.mesh = None
+        if len(devices) > 1:
+            from repro.distributed import shard_mesh
+            self.mesh = shard_mesh(devices)
 
     # ---------------------------------------------------------- program
     def _problem(self):
         cfg, tr, model = self.cfg, self.tr, self.model
 
-        @jax.jit
         def make(key):
             kw, *kb = jax.random.split(key, 1 + self.n_batches)
             zs = [model.rows(k, self.rows, cfg, tr["logk_lo"],
                              tr["logk_span"], tr["logk_power"]) for k in kb]
             return jnp.stack(zs), model.coupling(kw, cfg)
 
-        return make(seeds.key(self.seed))
+        if self.mesh is None:
+            return jax.jit(make)(seeds.key(self.seed))
+        # rows split over the mesh, the coupling on every chip
+        by_rows = NamedSharding(self.mesh, PartitionSpec(None, "data"))
+        everywhere = NamedSharding(self.mesh, PartitionSpec())
+        return jax.jit(make, out_shardings=(by_rows, everywhere))(
+            seeds.key(self.seed))
 
     def setup(self):
         from repro.core import odeint
@@ -72,6 +88,8 @@ class Driver:
                   rtol=cfg["rtol"], atol=cfg["atol"],
                   max_steps=cfg["max_steps"], use_pallas=cfg["use_pallas"],
                   batch_axis=0)
+        if self.mesh is not None:
+            kw["mesh"] = self.mesh
 
         def loss(z0, w):
             ys, st = odeint(field, z0, ts, (w,), **kw)
@@ -79,6 +97,9 @@ class Driver:
 
         z0s, self.w = self._problem()
         self.z0s = [z0s[i] for i in range(self.n_batches)]
+        if self.mesh is not None:
+            rows = NamedSharding(self.mesh, PartitionSpec("data"))
+            self.z0s = [jax.device_put(z, rows) for z in self.z0s]
         self.fn = jax.jit(jax.value_and_grad(
             loss, argnums=(0, 1), has_aux=True)).lower(
                 self.z0s[0], self.w).compile()
@@ -136,8 +157,10 @@ class Driver:
     # -------------------------------------------------------- reference
     def _reference(self, precision):
         cfg, tab = self.cfg, _tableau(self.cfg["solver"])
-        z0s, w = self._problem()
+        z0s, w = jax.device_get(self._problem())
         block = int(self.tr.get("ref_block_rows", self.rows))
+        devs = self.devices
+        w_on = [jax.device_put(w, d) for d in devs]
 
         @jax.jit
         def run_block(z0, w):
@@ -153,10 +176,15 @@ class Driver:
             gz, gw = jax.grad(loss, argnums=(0, 1))(z0, w)
             return sol.z1, gz, gw, sol.ok
 
+        # block j of every batch runs on chip j mod chips: the chips
+        # work through their blocks side by side
         outs = []
         for b in range(self.n_batches):
-            parts = [run_block(z0s[b, i:i + block], w)
-                     for i in range(0, self.rows, block)]
+            parts = []
+            for j, i in enumerate(range(0, self.rows, block)):
+                d = j % len(devs)
+                parts.append(run_block(
+                    jax.device_put(z0s[b, i:i + block], devs[d]), w_on[d]))
             z1 = np.concatenate([np.asarray(p[0]) for p in parts])
             gz = np.concatenate([np.asarray(p[1]) for p in parts])
             gw = sum(np.asarray(p[2], np.float64) for p in parts)

@@ -6,11 +6,15 @@ drives it through its first ``check_steps`` steps on
 ``TokenPipeline(seed)`` batches: those steps compile and warm up the
 step, and are the ones the correctness check compares.  The window then
 runs the same loop object step after step until the time is up,
-putting back the state of the end of set-up (a copy kept on the device)
-every ``replay_steps`` steps: every stretch of the window replays the
-same steps on the same batches, so each step does the same work however
-many fit in the window (the blocks' step counts, and with them a step's
-time, grow as training goes on).
+putting back a state every ``replay_steps`` steps: every stretch of the
+window replays the same steps on the same batches, so each step does
+the same work however many fit in the window (the blocks' step counts,
+and with them a step's time, grow as training goes on).  The traffic's
+``replay_from`` says which state: ``"copy"`` (the default), the state
+of the end of set-up, from a copy kept on the device; ``"seed"``, the
+state set-up started from, made again from the seed at the window's
+start and at every replay, so that no second copy of the state takes
+device memory.
 
 Correct: each of the first steps' losses, the first step's clipped
 gradient norm per leaf (read from AdamW's first moment after step 1)
@@ -34,6 +38,7 @@ from harness import seeds
 from harness.runner import BENCH_DIR, Check, load_module, span
 
 GRAD_FLOOR = 1e-3   # leaves under this share of the median gradient norm
+REPLAY_FROM = ("copy", "seed")
 
 
 def path_of(kp) -> str:
@@ -53,18 +58,30 @@ class Driver:
         self.ref = load_module(os.path.join(
             BENCH_DIR, "configs", config["name"] + ".py"))
         self.tokens_per_step = traffic["seq"] * traffic["batch"]
+        self.replay_from = traffic.get("replay_from", "copy")
+        if self.replay_from not in REPLAY_FROM:
+            raise ValueError(f"replay_from {self.replay_from!r} is not one "
+                             f"of {REPLAY_FROM}")
 
-    def _params(self, abstract):
-        """The seed's parameters in the program's tree, one jitted call."""
+    def _params_fn(self, abstract):
+        """Jitted: the seed's key -> its parameters in the program's tree,
+        made on the device in one call."""
         names = jax.tree_util.tree_map_with_path(
             lambda kp, _: path_of(kp), abstract)
 
-        @jax.jit
         def make(key):
             flat = self.ref.init_params(key, self.cfg)
             return jax.tree.map(lambda p: flat[p], names)
 
-        return make(seeds.key(self.seed))
+        return jax.jit(make)
+
+    def _initial_state(self):
+        """The seed's state at step 0: its parameters and fresh AdamW."""
+        from repro.train.state import TrainState
+
+        params = self._make(seeds.key(self.seed))
+        return TrainState(step=jnp.zeros((), jnp.int32), params=params,
+                          opt_state=self.opt.init(params))
 
     def setup(self):
         from repro.core.node_block import NodeConfig
@@ -73,26 +90,23 @@ class Driver:
         from repro.models.config import ModelConfig
         from repro.optim import adamw, cosine_warmup
         from repro.train import TrainLoop, TrainLoopConfig
-        from repro.train.state import TrainState
 
         cfg, tr, o = self.cfg, self.tr, self.cfg["optimizer"]
         model = build_model(ModelConfig(**cfg["model"]), RunConfig(
             compute_dtype=jnp.dtype(cfg["compute_dtype"]),
             param_dtype=jnp.dtype(cfg["param_dtype"]),
             node=NodeConfig(**cfg["node"]), remat="none"))
-        opt = adamw(cosine_warmup(o["peak_lr"], o["warmup_steps"],
-                                  o["total_steps"], o["final_frac"]),
-                    b1=o["b1"], b2=o["b2"], eps=o["eps"],
-                    weight_decay=o["weight_decay"])
+        self.opt = adamw(cosine_warmup(o["peak_lr"], o["warmup_steps"],
+                                       o["total_steps"], o["final_frac"]),
+                         b1=o["b1"], b2=o["b2"], eps=o["eps"],
+                         weight_decay=o["weight_decay"])
         self.pipe = TokenPipeline(vocab=cfg["model"]["vocab"],
                                   seq_len=tr["seq"], global_batch=tr["batch"],
                                   seed=self.seed, zipf_a=tr["zipf_a"])
-        params = self._params(model.abstract())
-        state = TrainState(step=jnp.zeros((), jnp.int32), params=params,
-                           opt_state=opt.init(params))
-        self.loop = TrainLoop(model, opt, TrainLoopConfig(
+        self._make = self._params_fn(model.abstract())
+        self.loop = TrainLoop(model, self.opt, TrainLoopConfig(
             microbatches=1, clip_norm=o["clip_norm"], ckpt_dir=None,
-            log_every=1), state)
+            log_every=1), self._initial_state())
 
         losses = []
         log = lambda s, m: losses.append(m["loss"])  # noqa: E731
@@ -106,10 +120,12 @@ class Driver:
             norms, is_leaf=lambda x: isinstance(x, tuple))}
         self.loop.run(self.pipe.batch, n_check, log_cb=log)
         self.losses = losses
-        self._copy = jax.jit(lambda t: jax.tree.map(jnp.copy, t))
-        self.state0 = self._copy(self.loop.state)
-        jax.block_until_ready(self.state0)
-        params0 = self._params(model.abstract())
+        self.state0 = None
+        if self.replay_from == "copy":
+            self._copy = jax.jit(lambda t: jax.tree.map(jnp.copy, t))
+            self.state0 = self._copy(self.loop.state)
+            jax.block_until_ready(self.state0)
+        params0 = self._make(seeds.key(self.seed))
         change = jax.tree_util.tree_map_with_path(
             lambda kp, a, b: (path_of(kp), jnp.sqrt(jnp.sum((a - b) ** 2))),
             self.loop.state.params, params0)
@@ -124,15 +140,18 @@ class Driver:
             with span("batch"):
                 return self.pipe.batch(step)
 
-        first = int(self.state0.step)
+        first = int(self.state0.step) if self.replay_from == "copy" else 0
         end = first + int(self.tr["replay_steps"])
+        # from the seed, the window starts by putting back step 0
+        pending = self.replay_from == "seed"
         skipped0, step_s = loop.skipped_steps, []
         t0 = time.perf_counter()
         while time.perf_counter() - t0 < seconds:
             t = time.perf_counter()
-            if loop.step >= end:
+            if pending or loop.step >= end:
                 with span("restore"):
-                    loop.state = self._copy(self.state0)
+                    loop.state = self.replay_start()
+                pending = False
             with span("step"):
                 loop.run(batch, loop.step + 1)
             step_s.append(time.perf_counter() - t)
@@ -147,6 +166,13 @@ class Driver:
             metrics={"train_tokens_per_s": tokens / elapsed},
             counters=dict(steps=steps, tokens=tokens,
                           tokens_per_step=self.tokens_per_step))
+
+    def replay_start(self):
+        """A fresh device copy of the state each stretch of the window
+        starts from."""
+        if self.replay_from == "copy":
+            return self._copy(self.state0)
+        return self._initial_state()
 
     def programs(self):
         loop = self.loop

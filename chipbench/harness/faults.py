@@ -14,10 +14,34 @@ def _patch(obj, attr, value) -> Callable[[], None]:
     return lambda: setattr(obj, attr, old)
 
 
+def _first_shard_only(axis: str):
+    """Identity whose cotangent is kept on the mesh's first shard alone
+    and zeroed on the others: under ``shard_map`` the sum over the
+    shards then returns the first chip's partial sum, as if the chips
+    had not exchanged theirs."""
+    import jax
+    import jax.numpy as jnp
+
+    @jax.custom_vjp
+    def ident(x):
+        return x
+
+    def fwd(x):
+        return x, None
+
+    def bwd(_, g):
+        first = jax.lax.axis_index(axis) == 0
+        return (jax.tree.map(lambda c: jnp.where(first, c, 0), g),)
+
+    ident.defvjp(fwd, bwd)
+    return ident
+
+
 def solve_fault(kind: str):
     """``odeint`` that leaves the state unchanged, solves half of the
-    batch (its answers stand in for the other half), or alters one
-    element of one answer."""
+    batch (its answers stand in for the other half), alters one element
+    of one answer, or (on a mesh) leaves out the exchange that sums the
+    coupling's gradient over the chips."""
     import jax.numpy as jnp
 
     import repro.core
@@ -25,6 +49,10 @@ def solve_fault(kind: str):
     real = repro.core.odeint
 
     def broken(f, z0, ts, args, **kw):
+        if kind == "no_exchange":
+            keep = _first_shard_only(kw["mesh"].axis_names[0])
+            return real(lambda t, z, *a: f(t, z, *keep(a)), z0, ts, args,
+                        **kw)
         if kind == "half_batch":
             h = z0.shape[0] // 2
             ys, st = real(f, z0[:h], ts, args, **kw)

@@ -29,8 +29,10 @@ BENCH_DIR = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 ROOT = os.path.dirname(BENCH_DIR)
 
 
-# the traced run measures at most this long: its trace is read within
-# the run's time limit and stays a few hundred MB on disk
+# the traced run measures at most this long on one chip, and this over
+# the number of chips on several (a trace holds every chip's ops): its
+# trace is read within the run's time limit and stays a few hundred MB
+# on disk
 TRACE_SECONDS = 10.0
 
 
@@ -169,6 +171,11 @@ def memory_peak_bytes(devices, live_bytes: int, programs, log=print) -> int:
     return max(alloc, live_bytes + work)
 
 
+def _tree_bytes(path: str) -> int:
+    return sum(os.path.getsize(os.path.join(d, f))
+               for d, _, files in os.walk(path) for f in files)
+
+
 def run_cell(found: Dict[str, Any], seed: int, seconds: float, trace: bool,
              devices, t_start: float, control: bool = False,
              trace_dir: Optional[str] = None,
@@ -204,11 +211,14 @@ def run_cell(found: Dict[str, Any], seed: int, seconds: float, trace: bool,
         os.makedirs(tdir, exist_ok=True)
         jax.profiler.start_trace(tdir)
     if trace:
-        seconds = min(seconds, TRACE_SECONDS)
+        seconds = min(seconds, TRACE_SECONDS / len(devices))
     with span("window") if trace else contextlib.nullcontext():
         out = drv.window(seconds)
     if trace:
+        t0 = time.perf_counter()
         jax.profiler.stop_trace()
+        log(f"trace written in {time.perf_counter() - t0:.2f} s: "
+            f"{_tree_bytes(tdir)} bytes")
     gc.enable()
     after = meter.snapshot()
     window_compiles = after[1] - before[1]

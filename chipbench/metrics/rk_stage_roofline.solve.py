@@ -3,9 +3,11 @@
 Bytes: what the stage combinations of every forward row trial in the
 window must move, counted from the tableau, the state width and the
 sum of row trials (``harness.counts.rk_bytes``); time: the device time
-of the rk_stage kernels in the traced window.  Trials of finished rows
-that ride along in the lockstep loop, and the backward sweep's replay,
-add time but no bytes, so the share reads low where they are large.
+of the rk_stage kernels in the traced window, summed over the chips, so
+that on several chips the bytes are held against all their peaks.
+Trials of finished rows that ride along in the lockstep loop, and the
+backward sweep's replay, add time but no bytes, so the share reads low
+where they are large.
 """
 
 from harness import counts

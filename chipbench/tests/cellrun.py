@@ -14,6 +14,10 @@ SMALL = {
     "solve.mlp256.heavy": dict(traffic=dict(rows=16, ref_block_rows=8,
                                             logk_span=2.0),
                                config=dict(max_steps=64)),
+    # 8 rows on each of 4 chips
+    "solve.mlp256.heavy.4chip": dict(traffic=dict(rows=32, ref_block_rows=8,
+                                                  logk_span=2.0),
+                                     config=dict(max_steps=64)),
     "train.node18.aca": dict(traffic=dict(seq=32, batch=4),
                              model=dict(n_layers=3, d_model=64, n_heads=4,
                                         n_kv_heads=4, head_dim=16, d_ff=160,
@@ -31,7 +35,10 @@ def small_cell(workload):
     return found
 
 
-def run(workload, seed=2 ** 40 + 5, seconds=0.5, control=False):
+SEED = 2 ** 40 + 5
+
+
+def run(workload, seed=SEED, seconds=0.5, control=False):
     found = small_cell(workload)
     chips = found["cell"]["chips"]
     return runner.run_cell(found, seed, seconds, False,
